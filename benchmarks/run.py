@@ -286,6 +286,8 @@ def main(argv=None) -> int:
     unknown = [b for b in args.benches if b not in mods]
     if unknown:
         ap.error(f"unknown bench(es) {unknown}; choose from {list(mods)}")
+    from repro import cache_dirs
+    cache_dirs.enable_compile_cache()
 
     rows = []
     for name in args.benches or list(mods):

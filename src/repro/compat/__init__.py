@@ -1,28 +1,23 @@
-"""Version-portability layer: every version-sensitive JAX surface, one import.
+"""Portability layer: every version-sensitive JAX surface, one import.
 
-The seed suite broke at the JAX API boundary three different ways (missing
-``jax.shard_map`` export, ``cost_analysis()`` list-vs-dict, the
-``check_vma``/``check_rep`` kwarg rename) while the paper's math passed
-untouched.  The policy that prevents a recurrence:
+The repo is floored at the installed JAX (0.9.0).  The policy that keeps an
+API move a one-file change:
 
 * **No module outside ``repro.compat`` imports ``shard_map``, calls
   ``cost_analysis()`` / ``make_mesh`` raw, decides Pallas interpret mode
   itself, touches the ``jax.tree``/``jax.tree_util`` namespaces directly, or
   constructs a ``NamedSharding`` raw.**  Grep-enforced by
   ``tests/test_compat.py``.
-* Probes are attribute/signature/behavior based, never version-string
-  comparisons — backports and vendored builds lie about versions.
-* ``capabilities()`` snapshots the probe results once per process; the kernel
+* ``capabilities()`` snapshots the backend once per process; the kernel
   dispatch registry (``repro.kernels.dispatch``), the dry-run env record, and
   the test env report all read that one snapshot.
 """
 from repro.compat.capabilities import Capabilities, capabilities
 from repro.compat.meshes import make_mesh
 from repro.compat.pallas import backend, pallas_interpret, pallas_native
-from repro.compat.shardings import NAMED_SHARDING_SOURCE, named_sharding
-from repro.compat.shmap import SHARD_MAP_SOURCE, shard_map
+from repro.compat.shardings import named_sharding
+from repro.compat.shmap import shard_map
 from repro.compat.trees import (
-    TREE_SOURCE,
     tree_flatten,
     tree_flatten_with_path,
     tree_leaves,
@@ -32,18 +27,16 @@ from repro.compat.trees import (
     tree_structure,
     tree_unflatten,
 )
-from repro.compat.versions import has_api, jax_version, jax_version_str
 from repro.compat.xla import cost_analysis, memory_analysis
 
 __all__ = [
     "Capabilities", "capabilities",
     "make_mesh",
     "backend", "pallas_interpret", "pallas_native",
-    "NAMED_SHARDING_SOURCE", "named_sharding",
-    "SHARD_MAP_SOURCE", "shard_map",
-    "TREE_SOURCE", "tree_flatten", "tree_flatten_with_path",
+    "named_sharding",
+    "shard_map",
+    "tree_flatten", "tree_flatten_with_path",
     "tree_leaves", "tree_map", "tree_map_with_path", "tree_reduce",
     "tree_structure", "tree_unflatten",
-    "has_api", "jax_version", "jax_version_str",
     "cost_analysis", "memory_analysis",
 ]

@@ -3,10 +3,6 @@
 ``capabilities()`` is probed lazily on first call and cached for the process:
 the kernel dispatch registry, the dry-run env record, and the test env report
 all read the same snapshot, so every layer agrees on what the runtime can do.
-
-Probes are behavioral where cheap (a trivial jit compile classifies the
-``cost_analysis()`` return shape) and attribute-based otherwise — never
-version-string comparisons.
 """
 from __future__ import annotations
 
@@ -15,7 +11,6 @@ from dataclasses import asdict, dataclass
 
 import jax
 
-from repro.compat import shmap, versions
 from repro.compat.pallas import backend, pallas_interpret, pallas_native
 
 
@@ -24,9 +19,6 @@ class Capabilities:
     jax_version: str
     backend: str
     device_count: int
-    shard_map_source: str            # "jax" | "jax.experimental.shard_map"
-    cost_analysis_shape: str         # "dict" | "list" | "unavailable"
-    has_make_mesh: bool              # native jax.make_mesh
     pallas_native: bool              # Pallas compiles to this backend
     pallas_interpret: bool           # interpret mode for Pallas calls
 
@@ -34,31 +26,13 @@ class Capabilities:
         return asdict(self)
 
 
-def _probe_cost_analysis_shape() -> str:
-    import jax.numpy as jnp
-    try:
-        compiled = jax.jit(lambda x: x + 1.0).lower(
-            jax.ShapeDtypeStruct((2,), jnp.float32)).compile()
-        ca = compiled.cost_analysis()
-    except Exception:
-        return "unavailable"
-    if isinstance(ca, (list, tuple)):
-        return "list"
-    if isinstance(ca, dict):
-        return "dict"
-    return "unavailable"
-
-
 @functools.lru_cache(maxsize=None)
 def capabilities() -> Capabilities:
     """Probe once, then serve the cached snapshot."""
     return Capabilities(
-        jax_version=versions.jax_version_str(),
+        jax_version=jax.__version__,
         backend=backend(),
         device_count=jax.device_count(),
-        shard_map_source=shmap.SHARD_MAP_SOURCE,
-        cost_analysis_shape=_probe_cost_analysis_shape(),
-        has_make_mesh=versions.has_api(jax, "make_mesh"),
         pallas_native=pallas_native(),
         pallas_interpret=pallas_interpret(),
     )

@@ -1,24 +1,19 @@
-"""Mesh construction across JAX versions.
+"""Mesh construction.
 
-``jax.make_mesh`` appeared in 0.4.34; older versions build a ``Mesh`` from
-``mesh_utils.create_device_mesh``.  One entry point, probe-based.
+``jax.make_mesh`` builds meshes with Explicit axis types by default, under
+which a gather on a sharded operand (``jnp.take`` of a vocab-sharded
+embedding) must state its ``out_sharding``.  The repo's sharding rules are
+written for Auto axes — the compiler propagates shardings — so every mesh
+is built here with all axes Auto.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_mesh(axis_shapes: tuple, axis_names: tuple, *, devices=None):
-    """Version-portable ``jax.make_mesh(axis_shapes, axis_names)``."""
-    mk = getattr(jax, "make_mesh", None)
-    if mk is not None:
-        try:
-            return mk(axis_shapes, axis_names, devices=devices)
-        except TypeError:                   # older signature without devices=
-            if devices is None:
-                return mk(axis_shapes, axis_names)
-            raise
-    from jax.experimental import mesh_utils
-    from jax.sharding import Mesh
-    dev = mesh_utils.create_device_mesh(axis_shapes, devices=devices)
-    return Mesh(dev, axis_names)
+    """``jax.make_mesh(axis_shapes, axis_names)`` with every axis Auto."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)

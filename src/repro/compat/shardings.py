@@ -1,38 +1,17 @@
-"""``NamedSharding`` construction across JAX versions.
+"""``NamedSharding`` construction.
 
-``jax.sharding.NamedSharding`` is the stable home on current JAX; before
-0.4.30-era releases the class lived under ``jax.experimental.sharding``
-(earliest as ``MeshPspecSharding``, with a positional-spec constructor).
 ``named_sharding(mesh, spec)`` is the one constructor the rest of the repo
-calls — probe-resolved, never version-compared — so a pinned older JAX keeps
-working without every call site growing a try/except (grep-enforced by
-``tests/test_compat.py``: no module outside ``repro.compat`` constructs a
-``NamedSharding`` raw).
+calls (grep-enforced by ``tests/test_compat.py``: no module outside
+``repro.compat`` constructs a ``NamedSharding`` raw), so specs given as
+tuples or ``None`` are normalized in one place.
 """
 from __future__ import annotations
 
-from jax.sharding import PartitionSpec
-
-
-def _resolve():
-    try:
-        from jax.sharding import NamedSharding
-        return NamedSharding, "jax.sharding"
-    except ImportError:
-        pass
-    try:
-        from jax.experimental.sharding import NamedSharding  # 0.4.x interim
-        return NamedSharding, "jax.experimental.sharding"
-    except ImportError:
-        from jax.experimental.sharding import MeshPspecSharding
-        return MeshPspecSharding, "jax.experimental.sharding.MeshPspecSharding"
-
-
-NamedShardingImpl, NAMED_SHARDING_SOURCE = _resolve()
+from jax.sharding import NamedSharding, PartitionSpec
 
 
 def named_sharding(mesh, spec=None):
-    """Version-portable ``NamedSharding(mesh, spec)``.
+    """``NamedSharding(mesh, spec)``.
 
     ``spec`` may be a ``PartitionSpec``, a tuple/list of axis entries (wrapped
     into one), or ``None`` (replicated)."""
@@ -40,4 +19,4 @@ def named_sharding(mesh, spec=None):
         spec = PartitionSpec()
     elif not isinstance(spec, PartitionSpec):
         spec = PartitionSpec(*spec)
-    return NamedShardingImpl(mesh, spec)
+    return NamedSharding(mesh, spec)

@@ -1,50 +1,17 @@
-"""``shard_map`` across JAX versions.
+"""``shard_map`` behind one stable signature.
 
-The API moved twice:
-
-* jax >= 0.6:   ``jax.shard_map(f, mesh=..., in_specs=..., out_specs=...,
-                check_vma=...)`` — top-level export, ``check_vma`` kwarg.
-* 0.4.x–0.5.x:  ``jax.experimental.shard_map.shard_map(f, mesh, in_specs,
-                out_specs, check_rep=...)`` — ``check_vma`` was then named
-                ``check_rep`` (same semantics: verify per-axis replication
-                invariants; False skips the check for ops the checker can't
-                type, e.g. ragged all_gathers).
-
-This module resolves the implementation and the kwarg name once at import and
-exposes one stable signature.  All repo code must import ``shard_map`` from
-``repro.compat`` — never from ``jax`` directly.
+All repo code imports ``shard_map`` from ``repro.compat`` — never from
+``jax`` directly — so a future API move is a one-file change.
 """
 from __future__ import annotations
 
-import inspect
 from typing import Callable
 
 import jax
 
 
-def _resolve() -> tuple[Callable, str | None, str]:
-    impl = getattr(jax, "shard_map", None)
-    source = "jax"
-    if impl is None:
-        from jax.experimental.shard_map import shard_map as impl  # type: ignore
-        source = "jax.experimental.shard_map"
-    params = inspect.signature(impl).parameters
-    if "check_vma" in params:
-        rep_kw = "check_vma"
-    elif "check_rep" in params:
-        rep_kw = "check_rep"
-    else:                                   # future removal: just drop it
-        rep_kw = None
-    return impl, rep_kw, source
-
-
-_IMPL, _REP_KW, SHARD_MAP_SOURCE = _resolve()
-
-
 def shard_map(f: Callable, *, mesh, in_specs, out_specs,
               check_vma: bool = True) -> Callable:
-    """Version-portable ``shard_map``; mirrors the modern keyword API."""
-    kwargs = {"mesh": mesh, "in_specs": in_specs, "out_specs": out_specs}
-    if _REP_KW is not None:
-        kwargs[_REP_KW] = check_vma
-    return _IMPL(f, **kwargs)
+    """``jax.shard_map`` with keyword-only mesh/spec arguments."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

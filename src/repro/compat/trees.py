@@ -1,45 +1,17 @@
-"""Pytree API aliasing across JAX versions.
+"""Pytree API aliases.
 
-The ``jax.tree`` namespace (``jax.tree.map``, ``.leaves``, ``.structure``,
-``.flatten``, ``.unflatten``, ``.reduce``) only exists on newer JAX; older
-releases spell the same operations ``jax.tree_util.tree_map`` etc., and the
-oldest ones deprecate-warn on the ``jax.tree_map`` top-level aliases.  One
-probe, one set of names — nothing outside ``repro.compat`` should care which
-spelling the installed JAX uses (grep-enforced by ``tests/test_compat.py``).
-
-Probe is attribute-based, not version-string-based, per the compat policy.
+One set of names for the ``jax.tree`` namespace, so nothing outside
+``repro.compat`` spells it (grep-enforced by ``tests/test_compat.py``).
 """
 from __future__ import annotations
 
 import jax
-from jax import tree_util as _tree_util
 
-if hasattr(jax, "tree") and hasattr(jax.tree, "map"):
-    TREE_SOURCE = "jax.tree"
-    tree_map = jax.tree.map
-    tree_leaves = jax.tree.leaves
-    tree_structure = jax.tree.structure
-    tree_flatten = jax.tree.flatten
-    tree_unflatten = jax.tree.unflatten
-    tree_reduce = jax.tree.reduce
-else:                                      # pre-jax.tree releases
-    TREE_SOURCE = "jax.tree_util"
-    tree_map = _tree_util.tree_map
-    tree_leaves = _tree_util.tree_leaves
-    tree_structure = _tree_util.tree_structure
-    tree_flatten = _tree_util.tree_flatten
-    tree_unflatten = _tree_util.tree_unflatten
-    tree_reduce = _tree_util.tree_reduce
-
-
-def _with_path(new_name: str, old_name: str):
-    # the path-aware APIs joined jax.tree later than the plain ones — probe
-    # each individually rather than assuming the namespace is all-or-nothing
-    mod = getattr(jax, "tree", None)
-    fn = getattr(mod, new_name, None) if mod is not None else None
-    return fn if fn is not None else getattr(_tree_util, old_name)
-
-
-tree_flatten_with_path = _with_path("flatten_with_path",
-                                    "tree_flatten_with_path")
-tree_map_with_path = _with_path("map_with_path", "tree_map_with_path")
+tree_map = jax.tree.map
+tree_leaves = jax.tree.leaves
+tree_structure = jax.tree.structure
+tree_flatten = jax.tree.flatten
+tree_unflatten = jax.tree.unflatten
+tree_reduce = jax.tree.reduce
+tree_flatten_with_path = jax.tree.flatten_with_path
+tree_map_with_path = jax.tree.map_with_path

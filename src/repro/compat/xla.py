@@ -1,29 +1,20 @@
-"""Compiled-artifact introspection across JAX/XLA versions.
+"""Compiled-artifact introspection.
 
-``Compiled.cost_analysis()`` has returned, depending on version:
-
-* a dict of ``{metric: value}``                     (modern jax)
-* a list with one such dict per partition/program   (0.4.x: ``[{...}]``)
-* ``None`` / raise ``NotImplementedError``          (some backends)
-
-``cost_analysis`` below always returns a plain (possibly empty) dict so
-callers can ``.get()`` without version branches.  This is the only place in
-the repo allowed to call the raw method.
+``cost_analysis`` always returns a plain (possibly empty) dict so callers
+can ``.get()`` without checks, including on backends that do not implement
+the analysis.  This is the only place in the repo allowed to call the raw
+method.
 """
 from __future__ import annotations
 
 
 def cost_analysis(compiled) -> dict:
-    """Normalized per-device cost analysis of a ``jax`` ``Compiled`` object."""
+    """Per-device cost analysis of a ``jax`` ``Compiled`` object."""
     try:
         ca = compiled.cost_analysis()
     except Exception:                                   # backend w/o support
         return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    if not isinstance(ca, dict):
-        return {}
-    return dict(ca)
+    return dict(ca) if isinstance(ca, dict) else {}
 
 
 def memory_analysis(compiled):

@@ -99,7 +99,11 @@ class ModelConfig:
     # §Perf levers (baseline off; flipped by the hillclimb)
     attn_causal_blocks: int = 0   # >1: causal chunk skipping (q-block unroll)
     kv_cache_dtype: str = ""      # "" = model dtype; "int8" = quantized cache
-    use_pallas: bool = False      # True on real TPU: swap in kernels/
+    # Pallas preference for contiguous attention (training / unpaged serving;
+    # on TPU it swaps in kernels/).  Paged attention and softmax_topk take
+    # the native kernels on TPU regardless; off the chip this asks for
+    # interpret mode (kernel tests).
+    use_pallas: bool = False
     # remat: "full" = recompute everything inside a block (layer inputs kept
     # by the scan carry — MaxText-style default for big models);
     # "block" = keep matmul outputs (dots_with_no_batch_dims); "none".

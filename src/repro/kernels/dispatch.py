@@ -22,8 +22,9 @@ autotune sweep over the ⊕-tree shape (``online_normalizer_blocked``'s
 ``block`` knob — §3.1 of the paper: any reduction tree gives the same
 ``(m, d)``, so the sweep is free to pick the fastest) and caches the winner
 per (backend, vocab, dtype).  The second call for the same key is a pure
-cache hit.  Decisions persist to an on-disk JSON cache (path overridable via
-``REPRO_AUTOTUNE_CACHE``; set it empty to disable) loaded at import, so a
+cache hit.  Decisions persist to an on-disk JSON cache inside the checkout
+(``repro.cache_dirs.AUTOTUNE_CACHE_PATH``; overridable via
+``REPRO_AUTOTUNE_CACHE``, set it empty to disable) loaded at import, so a
 serving restart skips the sweep entirely.
 
 Attention tile shapes go through the same seam: ``attention_tiles`` resolves
@@ -49,6 +50,7 @@ analytic error bounds live in ``repro.core.softmax_forms`` and
 """
 from __future__ import annotations
 
+import concurrent.futures
 import functools
 import json
 import os
@@ -58,7 +60,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from repro import compat
+from repro import cache_dirs, compat
 from repro import core
 from repro.obs import clock as obs_clock
 from repro.obs import kernels as obs_kernels
@@ -84,6 +86,12 @@ def register(op: str, *paths: str):
 
 def available(op: str) -> tuple[str, ...]:
     return tuple(_REGISTRY.get(op, ()))
+
+
+def implementation(op: str, path: str) -> Callable:
+    """The registered implementation of ``op`` on ``path``, bypassing
+    selection (to compare two paths on one backend)."""
+    return _REGISTRY[op][path]
 
 
 @functools.lru_cache(maxsize=None)
@@ -174,8 +182,7 @@ class TileDecision:
 # ---------------------------------------------------------------------------
 AUTOTUNE_CACHE_ENV = "REPRO_AUTOTUNE_CACHE"
 CACHE_SCHEMA_VERSION = 1       # stamped into every payload; mismatch → re-sweep
-_DEFAULT_CACHE_PATH = os.path.join(
-    os.path.expanduser("~"), ".cache", "repro", "autotune.json")
+_DEFAULT_CACHE_PATH = cache_dirs.AUTOTUNE_CACHE_PATH
 _CACHE_WARNED = False          # warn once per process, then stay quiet
 
 
@@ -298,6 +305,19 @@ def save_persisted_decisions(path: str | None = None) -> bool:
         return False
 
 
+def _outside_trace(fn: Callable):
+    """Run ``fn`` with no ambient JAX trace and return its result.
+
+    A sweep may fire while an outer ``jax.jit`` is tracing (the serving
+    step jits decode).  Its candidates must execute for real — traced, they
+    would time per-candidate tracing overhead and ``block_until_ready``
+    would no-op.  JAX's trace state is per thread, so a fresh thread runs
+    them concretely.  (``jax.ensure_compile_time_eval`` does not do: a
+    Pallas kernel cannot be traced under it.)"""
+    with concurrent.futures.ThreadPoolExecutor(max_workers=1) as pool:
+        return pool.submit(fn).result()
+
+
 def _time_blocked(x: Array, block: int) -> float:
     if compat.pallas_native():
         # time the thing being configured: the Pallas kernel at this tile
@@ -326,16 +346,14 @@ def block_decision(vocab: int, dtype=jnp.float32) -> BlockDecision:
         return hit
     global _SWEEPS
     _SWEEPS += 1
-    # The sweep may be triggered while an outer jax.jit is tracing (the
-    # serving step jits decode); without this guard the candidates become
-    # tracers, block_until_ready no-ops, and the "timings" are per-candidate
-    # tracing overhead.  ensure_compile_time_eval suspends the outer trace so
-    # the sweep runs (and measures) real execution; no-op when called eagerly.
-    with jax.ensure_compile_time_eval():
+
+    def sweep():
         cands = sorted({min(b, vocab) for b in BLOCK_CANDIDATES})
         x = (jnp.arange(_TUNE_ROWS * vocab, dtype=jnp.float32) % 251.0
              ).reshape(_TUNE_ROWS, vocab).astype(dtype)
-        timings = tuple((b, round(_time_blocked(x, b), 2)) for b in cands)
+        return tuple((b, round(_time_blocked(x, b), 2)) for b in cands)
+
+    timings = _outside_trace(sweep)
     winner = min(timings, key=lambda t: t[1])[0]
     decision = BlockDecision(backend=key[0], vocab=vocab, dtype=key[2],
                              block=winner, timings_us=timings)
@@ -436,28 +454,26 @@ def attention_tiles(op: str, *, kv_len: int, head_dim: int,
     global _SWEEPS
     if op == "flash_decode" and compat.pallas_native():
         _SWEEPS += 1
-        with jax.ensure_compile_time_eval():
-            cands = sorted({min(b, kv_len) for b in DECODE_BK_CANDIDATES
-                            if kv_len % min(b, kv_len) == 0})
-            timings = tuple(
-                (b, round(_time_decode_bk(kv_len, head_dim, dtype, b), 2))
-                for b in cands)
+        cands = sorted({min(b, kv_len) for b in DECODE_BK_CANDIDATES
+                        if kv_len % min(b, kv_len) == 0})
+        timings = _outside_trace(lambda: tuple(
+            (b, round(_time_decode_bk(kv_len, head_dim, dtype, b), 2))
+            for b in cands))
         defaults["bk"] = min(timings, key=lambda t: t[1])[0]
     elif op in _PREFILL_TILE_OPS and compat.pallas_native():
         _SWEEPS += 1
-        with jax.ensure_compile_time_eval():
-            bqs = sorted({min(c, kv_len) for c in PREFILL_TILE_CANDIDATES})
-            if op == "flash_attention_paged":   # bk pinned to the page size
-                cands = [(bq, 0) for bq in bqs]
-            else:
-                bks = sorted({min(c, kv_len) for c in PREFILL_TILE_CANDIDATES
-                              if kv_len % min(c, kv_len) == 0})
-                cands = [(bq, bk) for bq in bqs for bk in bks]
-            timings = tuple(
-                ((bq, bk),
-                 round(_time_prefill_tiles(op, kv_len, head_dim, dtype,
-                                           bq, bk), 2))
-                for bq, bk in cands)
+        bqs = sorted({min(c, kv_len) for c in PREFILL_TILE_CANDIDATES})
+        if op == "flash_attention_paged":       # bk pinned to the page size
+            cands = [(bq, 0) for bq in bqs]
+        else:
+            bks = sorted({min(c, kv_len) for c in PREFILL_TILE_CANDIDATES
+                          if kv_len % min(c, kv_len) == 0})
+            cands = [(bq, bk) for bq in bqs for bk in bks]
+        timings = _outside_trace(lambda: tuple(
+            ((bq, bk),
+             round(_time_prefill_tiles(op, kv_len, head_dim, dtype, bq, bk),
+                   2))
+            for bq, bk in cands))
         best_bq, best_bk = min(timings, key=lambda t: t[1])[0]
         defaults["bq"] = best_bq
         if "bk" in defaults:
@@ -664,14 +680,8 @@ def _paged_decode_attention_pallas(cfg, q, k, v, *, q_offset, kv_valid_len,
                                    block_tables, scale, k_scale=None,
                                    v_scale=None):
     """Single-token decode over paged KV on the Pallas streaming kernel.
-    The kernel bakes in the default 1/sqrt(d) scale; a custom scale falls
-    back to the gather + chunked XLA form.  Quantized pools pass their
-    scale pages through — the kernel dequantizes tile-local."""
-    if scale is not None and scale != q.shape[-1] ** -0.5:
-        return _paged_decode_attention_xla(
-            cfg, q, k, v, q_offset=q_offset, kv_valid_len=kv_valid_len,
-            block_tables=block_tables, scale=scale, k_scale=k_scale,
-            v_scale=v_scale)
+    Quantized pools pass their scale pages through — the kernel dequantizes
+    tile-local."""
     from repro.kernels import ops
     return ops.paged_flash_decode(q[:, 0], k, v, block_tables,
                                   kv_valid_len, k_scale_pool=k_scale,
@@ -695,40 +705,33 @@ def _paged_decode_attention_xla(cfg, q, k, v, *, q_offset, kv_valid_len,
 
 def _paged_sdpa(cfg, q, k, v, *, causal, q_offset, kv_valid_len, scale,
                 decode, block_tables, k_scale=None, v_scale=None):
-    """Routing for block-table attention: mirrors the contiguous policy.
+    """Routing for block-table attention: ``select_path``'s own policy.
 
-    Decode: Pallas paged streaming kernel where native under a Pallas
-    preference, else the gather + chunked XLA form.  Prefill: Pallas
-    (compiled or interpret) under a Pallas preference unless the shape is
-    kernel-unrepresentable (custom scale, value-dim ≠ key-dim), else XLA.
+    Decode and prefill take the Pallas paged kernels wherever Pallas
+    compiles natively.  Off the chip, ``cfg.use_pallas`` asks for the
+    prefill kernel in interpret mode (decode has no interpret entry);
+    otherwise the gather + chunked XLA form runs.  Shapes the kernels cannot
+    represent (custom scale, value-dim ≠ key-dim) always take the XLA form.
     Quantized pools (``k_scale``/``v_scale`` pages set) ride the same
     routing — every path dequantizes after its gather.  Paged serving is
-    single-host: an ambient ShardContext is a routing bug, not a fallback
-    case."""
+    single-host, so no Pallas call here needs partitioning: an ambient
+    ShardContext is a routing bug, not a fallback case."""
     from repro.distributed import context
     if context.get() is not None:
         raise NotImplementedError(
             "paged KV attention has no sharded ⊕-merge form yet; drop the "
             "ShardContext or serve unpaged")
-    kernel_ok = ((scale is None or scale == q.shape[-1] ** -0.5)
-                 and v.shape[-1] == q.shape[-1])
-    if decode:
-        if cfg.use_pallas and \
-                select_path("paged_decode_attention") == PATH_PALLAS:
-            fn = _REGISTRY["paged_decode_attention"][PATH_PALLAS]
-        else:
-            fn = _REGISTRY["paged_decode_attention"][PATH_XLA]
-        return fn(cfg, q, k, v, q_offset=q_offset, kv_valid_len=kv_valid_len,
-                  block_tables=block_tables, scale=scale, k_scale=k_scale,
-                  v_scale=v_scale)
-    if cfg.use_pallas and kernel_ok:
-        path = select_path("paged_attention", prefer_pallas=True)
+    op = "paged_decode_attention" if decode else "paged_attention"
+    if ((scale is None or scale == q.shape[-1] ** -0.5)
+            and v.shape[-1] == q.shape[-1]):
+        _, fn = lookup(op, prefer_pallas=cfg.use_pallas)
     else:
-        path = PATH_XLA
-    return _REGISTRY["paged_attention"][path](
-        cfg, q, k, v, causal=causal, q_offset=q_offset,
-        kv_valid_len=kv_valid_len, block_tables=block_tables, scale=scale,
-        k_scale=k_scale, v_scale=v_scale)
+        obs_kernels.record_path(op, PATH_XLA, prefer_pallas=cfg.use_pallas)
+        fn = implementation(op, PATH_XLA)
+    kw = {} if decode else {"causal": causal}
+    return fn(cfg, q, k, v, q_offset=q_offset, kv_valid_len=kv_valid_len,
+              block_tables=block_tables, scale=scale, k_scale=k_scale,
+              v_scale=v_scale, **kw)
 
 
 # ---------------------------------------------------------------------------

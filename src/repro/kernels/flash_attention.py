@@ -316,9 +316,9 @@ def _make_paged_kernel(*, scale: float, causal: bool, bq: int, bs: int,
                 # dequantize AFTER the HBM read: int8 page × per-position
                 # scale column, gathered through the same clamped table entry
                 return ((k_ref[0, 0].astype(jnp.float32)
-                         * ks_ref[0, 0].astype(jnp.float32)[:, None]),
+                         * ks_ref[0, 0].astype(jnp.float32)),   # [BS, 1]
                         (v_ref[0, 0].astype(jnp.float32)
-                         * vs_ref[0, 0].astype(jnp.float32)[:, None]))
+                         * vs_ref[0, 0].astype(jnp.float32)))
 
             body(b, i, j, q_ref, load_kv, o_ref, lse_ref, m_sc, d_sc, acc_sc,
                  qoff_ref, vlen_ref)
@@ -365,9 +365,11 @@ def flash_attention_paged_pallas(q: jax.Array, k_pool: jax.Array,
     kernel above.  The online ``(m, d)`` carry (paper Alg. 3) is what makes
     one pass over an arbitrary page list correct.
 
-    ``k_scale_pool``/``v_scale_pool`` [P, Hkv, BS] set selects the quantized
-    form: int8 pools plus per-position scale pages gathered through the SAME
-    clamped table index and applied in VMEM before the online update.
+    ``k_scale_pool``/``v_scale_pool`` [P, Hkv, BS, 1] set selects the
+    quantized form: int8 pools plus per-position scale pages gathered through
+    the SAME clamped table index and applied in VMEM before the online
+    update (the trailing unit axis keeps the scale block TPU-tileable, as in
+    ``flash_decode_paged_pallas``).
     """
     b, hq, tq, dh = q.shape
     _, hkv, bs, _ = k_pool.shape
@@ -391,9 +393,6 @@ def flash_attention_paged_pallas(q: jax.Array, k_pool: jax.Array,
         jc = jnp.minimum(j, last_live_block(b_, i, qoff_ref, vlen_ref))
         return (tbl_ref[b_, jc], h // g, 0, 0)
 
-    def scale_index(qoff_ref, vlen_ref, tbl_ref, b_, h, i, j):
-        return kv_index(qoff_ref, vlen_ref, tbl_ref, b_, h, i, j)[:3]
-
     in_specs = [
         pl.BlockSpec((1, 1, bq, dh),
                      lambda b_, h, i, j, qo, vl, tbl: (b_, h, i, 0)),
@@ -407,11 +406,11 @@ def flash_attention_paged_pallas(q: jax.Array, k_pool: jax.Array,
     operands = [q, k_pool, v_pool]
     if quantized:
         in_specs += [
-            pl.BlockSpec((1, 1, bs),
-                         lambda b_, h, i, j, qo, vl, tbl: scale_index(
+            pl.BlockSpec((1, 1, bs, 1),
+                         lambda b_, h, i, j, qo, vl, tbl: kv_index(
                              qo, vl, tbl, b_, h, i, j)),
-            pl.BlockSpec((1, 1, bs),
-                         lambda b_, h, i, j, qo, vl, tbl: scale_index(
+            pl.BlockSpec((1, 1, bs, 1),
+                         lambda b_, h, i, j, qo, vl, tbl: kv_index(
                              qo, vl, tbl, b_, h, i, j)),
         ]
         operands += [k_scale_pool, v_scale_pool]

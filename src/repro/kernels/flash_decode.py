@@ -147,9 +147,9 @@ def _make_paged_kernel(*, scale: float, g: int, bs: int, n_blocks: int,
                 # dequantize AFTER the HBM read: int8 page × per-position
                 # scale column, both fetched through the same table entry
                 k = (k_ref[0, 0].astype(jnp.float32)
-                     * ks_ref[0, 0].astype(jnp.float32)[:, None])  # [BS, D]
+                     * ks_ref[0, 0].astype(jnp.float32))    # [BS, D]·[BS, 1]
                 v = (v_ref[0, 0].astype(jnp.float32)
-                     * vs_ref[0, 0].astype(jnp.float32)[:, None])
+                     * vs_ref[0, 0].astype(jnp.float32))
                 _update(j, vlen, q_ref, k, v, m_sc, d_sc, acc_sc)
 
             pl.when(j == n_blocks - 1)(
@@ -192,10 +192,13 @@ def flash_decode_paged_pallas(q: jax.Array, k_pool: jax.Array,
     fetch scheduled, compute skipped via ``pl.when``), and the tail block's
     out-of-range columns are masked to −inf before the online update.
 
-    ``k_scale_pool``/``v_scale_pool`` [P, Hkv, BS] set selects the quantized
-    form: the pools are int8 and each grid step additionally streams the
-    page's per-position scale column — through the SAME clamped table index —
-    dequantizing in VMEM before the online update.
+    ``k_scale_pool``/``v_scale_pool`` [P, Hkv, BS, 1] set selects the
+    quantized form: the pools are int8 and each grid step additionally
+    streams the page's per-position scale column — through the SAME clamped
+    table index — dequantizing in VMEM before the online update.  The
+    trailing unit axis makes the scale block's last two dims (BS, 1), which
+    the TPU compiler accepts; a (1, 1, BS) block of a [P, Hkv, BS] array is
+    refused (its second-to-last dim, 1, is neither a multiple of 8 nor Hkv).
     """
     b, hq, dh = q.shape
     _, hkv, bs, _ = k_pool.shape
@@ -207,9 +210,6 @@ def flash_decode_paged_pallas(q: jax.Array, k_pool: jax.Array,
     def page_index(tbl_ref, vlen_ref, b_, h, j):
         last = jnp.maximum((vlen_ref[b_] + bs - 1) // bs - 1, 0)
         return (tbl_ref[b_, jnp.minimum(j, last)], h, 0, 0)
-
-    def scale_index(tbl_ref, vlen_ref, b_, h, j):
-        return page_index(tbl_ref, vlen_ref, b_, h, j)[:3]
 
     in_specs = [
         pl.BlockSpec((1, 1, g, dh),
@@ -224,12 +224,12 @@ def flash_decode_paged_pallas(q: jax.Array, k_pool: jax.Array,
     operands = [qg, k_pool, v_pool]
     if quantized:
         in_specs += [
-            pl.BlockSpec((1, 1, bs),
-                         lambda b_, h, j, tbl, vl: scale_index(tbl, vl, b_,
-                                                               h, j)),
-            pl.BlockSpec((1, 1, bs),
-                         lambda b_, h, j, tbl, vl: scale_index(tbl, vl, b_,
-                                                               h, j)),
+            pl.BlockSpec((1, 1, bs, 1),
+                         lambda b_, h, j, tbl, vl: page_index(tbl, vl, b_,
+                                                              h, j)),
+            pl.BlockSpec((1, 1, bs, 1),
+                         lambda b_, h, j, tbl, vl: page_index(tbl, vl, b_,
+                                                              h, j)),
         ]
         operands += [k_scale_pool, v_scale_pool]
     grid_spec = pltpu.PrefetchScalarGridSpec(

@@ -294,6 +294,12 @@ def flash_decode(q: Array, k_cache: Array, v_cache: Array,
 # path.  Block tables are CONSUMED here; building them is the exclusive
 # business of ``repro.serving.paged`` (grep-enforced).
 # ---------------------------------------------------------------------------
+def _scale_column(pool: Array | None) -> Array | None:
+    """Scale pages [P, Hkv, BS] → the kernels' [P, Hkv, BS, 1] view (a free
+    reshape; the cache layout keeps the block axis at leaf position 1)."""
+    return None if pool is None else pool[..., None]
+
+
 def paged_flash_decode(q: Array, k_pool: Array, v_pool: Array,
                        block_tables: Array, kv_valid_len: Array, *,
                        k_scale_pool: Array | None = None,
@@ -307,8 +313,8 @@ def paged_flash_decode(q: Array, k_pool: Array, v_pool: Array,
     select the quantized (int8 pools + per-position scale pages) form."""
     return flash_decode_paged_pallas(q, k_pool, v_pool, block_tables,
                                      kv_valid_len,
-                                     k_scale_pool=k_scale_pool,
-                                     v_scale_pool=v_scale_pool,
+                                     k_scale_pool=_scale_column(k_scale_pool),
+                                     v_scale_pool=_scale_column(v_scale_pool),
                                      interpret=compat.pallas_interpret())
 
 
@@ -338,6 +344,8 @@ def paged_flash_attention(q: Array, k_pool: Array, v_pool: Array,
                                     (b,))
     out, _ = flash_attention_paged_pallas(
         jnp.swapaxes(q, 1, 2), k_pool, v_pool, q_offset, kv_valid_len,
-        block_tables, causal=causal, bq=bq, k_scale_pool=k_scale_pool,
-        v_scale_pool=v_scale_pool, interpret=compat.pallas_interpret())
+        block_tables, causal=causal, bq=bq,
+        k_scale_pool=_scale_column(k_scale_pool),
+        v_scale_pool=_scale_column(v_scale_pool),
+        interpret=compat.pallas_interpret())
     return jnp.swapaxes(out, 1, 2)

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 import repro.configs as configs
+from repro import cache_dirs
 from repro.models import encdec, layers as L, transformer
 from repro.obs import clock as obs_clock
 from repro.obs import kernels as obs_kernels
@@ -329,6 +330,7 @@ def main(argv=None):
                          "quantized K/V with per-position scales, dequantized "
                          "in the gather; empty = config default)")
     args = ap.parse_args(argv)
+    cache_dirs.enable_compile_cache()
 
     cfg = (configs.get_smoke(args.arch) if args.smoke
            else configs.get(args.arch))

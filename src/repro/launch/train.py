@@ -10,9 +10,10 @@ from __future__ import annotations
 import argparse
 
 import jax
+from jax.sharding import PartitionSpec as P
 
 import repro.configs as configs
-from repro import compat
+from repro import cache_dirs, compat
 from repro.configs.base import OptimizerConfig, ParallelConfig, RunConfig
 from repro.data.synthetic import SyntheticConfig, SyntheticDataset
 from repro.distributed import context, sharding
@@ -36,6 +37,7 @@ def main(argv=None):
                     help="e.g. 2x4 → mesh (data=2, model=4); default: all "
                          "devices on the data axis")
     args = ap.parse_args(argv)
+    cache_dirs.enable_compile_cache()
 
     cfg = (configs.get_smoke(args.arch) if args.smoke
            else configs.get(args.arch))
@@ -71,7 +73,8 @@ def main(argv=None):
     with mesh, context.use(ctx):
         params, opt_state, history = loop.run(
             run_cfg, steps=args.steps, train_step=step_fn,
-            params=params, opt_state=opt_state, dataset=ds)
+            params=params, opt_state=opt_state, dataset=ds,
+            batch_sharding=compat.named_sharding(mesh, P(par.data_axes)))
     losses = [h["loss"] for h in history if "loss" in h]
     if losses:
         print(f"first loss {losses[0]:.4f} → last loss {losses[-1]:.4f}")

@@ -54,7 +54,7 @@ class StragglerWatchdog:
 
 
 def run(run_cfg: RunConfig, *, steps: int, train_step: Callable,
-        params, opt_state, shardings=None,
+        params, opt_state, shardings=None, batch_sharding=None,
         dataset: Optional[SyntheticDataset] = None,
         inject_failure: Optional[Callable[[int], None]] = None,
         log: Callable[[str], None] = print):
@@ -62,6 +62,8 @@ def run(run_cfg: RunConfig, *, steps: int, train_step: Callable,
 
     Returns (params, opt_state, history).  Restores from the newest committed
     checkpoint in ``run_cfg.checkpoint_dir`` if one exists (restart path).
+    ``batch_sharding`` places each batch (sharded over the data axes under
+    a mesh); unset, batches go to the default device.
     """
     cfg = run_cfg.model
     ckpt = CheckpointManager(run_cfg.checkpoint_dir,
@@ -85,7 +87,8 @@ def run(run_cfg: RunConfig, *, steps: int, train_step: Callable,
     step = start
     while step < steps:
         batch = dataset.batch(step)
-        batch = compat.tree_map(lambda x: jax.numpy.asarray(x), batch)
+        batch = compat.tree_map(
+            lambda x: jax.device_put(x, batch_sharding), batch)
         if inject_failure is not None:
             inject_failure(step)          # may raise — simulated node death
         t0 = obs_clock.monotonic()

@@ -9,16 +9,14 @@ bytes (verified in the dry-run HLO, §Perf).
 """
 from __future__ import annotations
 
-import functools
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
 
 from repro import compat
 from repro.configs.base import RunConfig
-from repro.distributed import compression, sharding
+from repro.distributed import compression
 from repro.models import encdec, layers as L, transformer
 from repro.optim import adamw
 
@@ -75,20 +73,3 @@ def init_state(run: RunConfig, rng) -> tuple[PyTree, PyTree, PyTree]:
     opt_state = adamw.init(params)
     return params, opt_state, axes
 
-
-def jit_train_step(run: RunConfig, mesh: Mesh, axes: PyTree):
-    """jit with explicit in/out shardings for the production mesh."""
-    cfg = run.model
-    par = sharding.derive_parallel(cfg, mesh, run.parallel)
-    p_sh = sharding.param_sharding(axes, cfg, par, mesh)
-    opt_sh = adamw.AdamWState(
-        step=compat.named_sharding(mesh, P()),
-        mu=p_sh, nu=p_sh)
-    bspec = compat.named_sharding(mesh, P(par.data_axes, None))
-    step = make_train_step(run)
-    return jax.jit(
-        step,
-        in_shardings=(p_sh, opt_sh, None),
-        out_shardings=(p_sh, opt_sh, None),
-        donate_argnums=(0, 1),
-    ), p_sh, opt_sh

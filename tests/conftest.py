@@ -1,8 +1,7 @@
 """Suite-wide setup: import paths + environment report.
 
 The env report prints the exact portability surface the compat layer probes
-(JAX version, backend, host device count, shard_map source, cost_analysis
-shape, Pallas mode) at the top of every pytest run, so a red CI log starts
+(JAX version, backend, host device count, Pallas mode) at the top of every pytest run, so a red CI log starts
 with the facts that usually explain it.
 """
 from __future__ import annotations
@@ -23,7 +22,7 @@ for p in (_SRC, _TESTS_DIR):
 # Hermetic autotune persistence: the dispatch registry reads this env var at
 # import and writes to it on every new decision.  Point it at a per-run temp
 # file (unless the caller pinned one) so the suite neither pollutes nor reads
-# the developer's real ~/.cache/repro/autotune.json.
+# the checkout's own .cache/autotune.json.
 if "REPRO_AUTOTUNE_CACHE" not in os.environ:
     import tempfile
     os.environ["REPRO_AUTOTUNE_CACHE"] = os.path.join(
@@ -44,7 +43,5 @@ def pytest_report_header(config):
     return (
         f"repro env: jax {caps.jax_version} | backend {caps.backend} | "
         f"host devices {caps.device_count} | "
-        f"shard_map from {caps.shard_map_source} | "
-        f"cost_analysis returns {caps.cost_analysis_shape} | "
         f"pallas {'native' if caps.pallas_native else 'interpret'} | {hyp}"
     )

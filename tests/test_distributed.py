@@ -23,8 +23,9 @@ def run_py(code: str, devices: int = 8, timeout: int = 560) -> str:
 
 def test_sharded_decode_attention_and_topk():
     run_py("""
+from repro import compat
 import jax, jax.numpy as jnp, numpy as np, jax.random as jr
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = compat.make_mesh((2, 4), ('data', 'model'))
 from repro.distributed.decode_attention import sharded_decode_attention, sharded_topk_sample
 from repro.kernels import ref
 from repro import core
@@ -46,8 +47,9 @@ print('OK')
 
 def test_int8_allreduce():
     run_py("""
+from repro import compat
 import jax, jax.numpy as jnp, numpy as np
-mesh = jax.make_mesh((8,), ('data',))
+mesh = compat.make_mesh((8,), ('data',))
 from repro.distributed.compression import int8_allreduce
 x = jnp.linspace(-2, 2, 1024)
 with mesh:
@@ -61,6 +63,7 @@ def test_sharded_train_step_matches_single_device():
     """The pjit'd train step on a 2x4 mesh must produce the same params as
     the unsharded step (same batch, same init)."""
     run_py("""
+from repro import compat
 import jax, jax.numpy as jnp, numpy as np
 import repro.configs as configs
 from repro.configs.base import OptimizerConfig, ParallelConfig, RunConfig
@@ -76,7 +79,7 @@ batch = jax.tree.map(jnp.asarray, ds.batch(0))
 # single device
 p1, _, m1 = make_train_step(run)(jax.tree.map(jnp.copy, params), jax.tree.map(jnp.copy, opt), batch)
 # sharded
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = compat.make_mesh((2, 4), ('data', 'model'))
 par = sharding.derive_parallel(cfg, mesh, run.parallel)
 p_sh = sharding.param_sharding(axes, cfg, par, mesh)
 params_s = jax.device_put(params, p_sh)
@@ -96,9 +99,10 @@ print('OK loss', float(m1['loss']))
 def test_dryrun_smoke_cells(arch):
     """Every builder path lowers+compiles on a small mesh (smoke configs)."""
     run_py(f"""
+from repro import compat
 import jax
 from repro.launch import dryrun
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = compat.make_mesh((2, 4), ('data', 'model'))
 for shape in ('train_4k', 'prefill_32k', 'decode_32k'):
     rec = dryrun.run_cell({arch!r}, shape, multi_pod=False, mesh=mesh,
                           smoke=True, verbose=False)
@@ -111,9 +115,10 @@ print('OK')
 
 def test_dryrun_multipod_smoke():
     run_py("""
+from repro import compat
 import jax
 from repro.launch import dryrun
-mesh = jax.make_mesh((2, 2, 2), ('pod', 'data', 'model'))
+mesh = compat.make_mesh((2, 2, 2), ('pod', 'data', 'model'))
 rec = dryrun.run_cell('smollm-360m', 'train_4k', multi_pod=True, mesh=mesh,
                       smoke=True, verbose=False)
 assert rec['status'] == 'ok'
@@ -127,6 +132,7 @@ print('OK')
 def test_elastic_reshard_restore():
     """Save sharded on a 2x4 mesh, restore onto 4x2 — elastic scaling."""
     run_py("""
+from repro import compat
 import jax, jax.numpy as jnp, numpy as np
 import repro.configs as configs
 from repro.checkpoint.manager import CheckpointManager
@@ -139,12 +145,12 @@ run = RunConfig(model=cfg)
 params, _, axes = init_state(run, jax.random.PRNGKey(0))
 d = tempfile.mkdtemp()
 mgr = CheckpointManager(d)
-mesh1 = jax.make_mesh((2, 4), ('data', 'model'))
+mesh1 = compat.make_mesh((2, 4), ('data', 'model'))
 par1 = sharding.derive_parallel(cfg, mesh1)
 sh1 = sharding.param_sharding(axes, cfg, par1, mesh1)
 p1 = jax.device_put(params, sh1)
 mgr.save(1, {'params': p1}, blocking=True)
-mesh2 = jax.make_mesh((4, 2), ('data', 'model'))
+mesh2 = compat.make_mesh((4, 2), ('data', 'model'))
 par2 = sharding.derive_parallel(cfg, mesh2)
 sh2 = sharding.param_sharding(axes, cfg, par2, mesh2)
 like = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), {'params': params})
