@@ -62,7 +62,7 @@ def run(smoke: bool = False, paged: bool = False, priorities: bool = False,
         affinity: bool = True, obs: bool = False,
         arch: str = "smollm_360m", kv: str = "") -> list:
     import repro.configs as configs
-    from repro.models import encdec, layers as L, transformer
+    from repro.models.params import init_params
     from repro.serving import cache_family, scheduler
     from repro.serving.engine_api import Engine
     from repro.serving.router import ReplicaRouter
@@ -96,8 +96,7 @@ def run(smoke: bool = False, paged: bool = False, priorities: bool = False,
         paged_kw["num_blocks"] = (slots + 1) * (slot_len // block_size) // 2
     paged_kw["preempt"] = preempt
 
-    init_fn = encdec.init if family.kind == "encdec" else transformer.init
-    params, _ = L.split_params(init_fn(jax.random.PRNGKey(0), cfg))
+    params, _ = init_params(cfg, jax.random.PRNGKey(0))
     if replicas:
         # prefix-heavy: four groups, each sharing its own system prompt —
         # the SAME workload for every replica count / routing policy, so

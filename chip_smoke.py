@@ -11,13 +11,14 @@ the path ``python -m repro.launch.serve --continuous --paged`` drives.  The
 smoke then checks that the paged attention ops and ``softmax_topk`` resolved
 to the native Pallas kernels, compares one paged decode step, one paged
 prefill chunk (bf16 and int8 pools) and ``softmax_topk`` against their
-reference forms on the chip, checks the model's logits are finite, and
-serves a few requests again with int8 KV.
+reference forms on the chip (and checks that each comparison catches a
+planted fault in the block table), checks the model's logits are finite,
+and serves a few requests again with int8 KV.
 
 Four chips: only the sharded train step of smollm_360m at published widths,
 for a few steps on two meshes (all four chips on ``data``, the launcher's
 default; and 2×2 ``data``×``model``), each compared with the same steps on
-one device.
+one device, with fp32 and with bf16 weights.
 
 Everything runs in this one process (a chip belongs to one process).  Times
 printed here are a smoke run's, not measurements.  Any failed phase exits
@@ -69,22 +70,6 @@ class CompileClock:
     def _on_event(self, event: str, duration: float, **_) -> None:
         if event == "/jax/core/compile/backend_compile_duration":
             self.seconds += duration
-
-
-def init_params(cfg, seed: int):
-    """Random weights from ``seed`` as one compiled program (an op-by-op
-    init compiles every small op on its own: ~50 s on a v5e).  Returns
-    (params, logical axes)."""
-    import jax
-    from repro.models import layers as L, transformer
-    axes = {}
-
-    def values(key):
-        v, axes["tree"] = L.split_params(transformer.init(key, cfg))
-        return v
-
-    params = jax.block_until_ready(jax.jit(values)(jax.random.PRNGKey(seed)))
-    return params, axes["tree"]
 
 
 # ---------------------------------------------------------------------------
@@ -209,33 +194,85 @@ def _paged_operands(cfg, quantized: bool, seed: int):
     return kv, scales, tables, rng
 
 
-def _compare(name, got, want, atol, rtol, why):
+def _drop_last_page(tables, lens):
+    """The planted fault: each row's last live page is read from the row's
+    first page instead, as an off-by-one page clamp or a stale table entry
+    in the kernel's gather would do."""
+    import jax.numpy as jnp
+    import numpy as np
+    t = np.array(tables)
+    for r, n in enumerate(-(-np.asarray(lens) // BLOCK)):
+        if n >= 2:
+            t[r, n - 1] = t[r, 0]
+    return jnp.asarray(t)
+
+
+def _vector_errors(got, want):
+    """‖got − want‖₂ / ‖want‖₂ of each attention output vector (one head
+    of one query token, over the head dim), as [B, T, H]."""
     import numpy as np
     got = np.asarray(got, np.float32)
     want = np.asarray(want, np.float32)
-    err = np.abs(got - want)
-    bound = atol + rtol * np.abs(want)
-    print(f"compare {name}: max|diff| {err.max():.3e}, worst diff/bound "
-          f"{(err / bound).max():.3f} (atol {atol:g}, rtol {rtol:g}: {why})")
+    return (np.linalg.norm(got - want, axis=-1)
+            / np.maximum(np.linalg.norm(want, axis=-1), 1e-6))
+
+
+def _compare_vectors(name, got, want, rtol, why):
+    import numpy as np
+    worst = _vector_errors(got, want).max()
+    print(f"compare {name}: worst relative error {worst:.3e}, "
+          f"{worst / rtol:.3f} of the bound ({rtol:g}: {why})")
+    check(np.isfinite(np.asarray(got, np.float32)).all(),
+          f"{name}: non-finite output")
+    check(worst <= rtol, f"{name}: outside tolerance")
+
+
+def _check_fault_caught(name, got, want, rtol, rows):
+    """The comparison must fail on every one of ``rows`` when the kernel
+    runs with a planted fault."""
+    import numpy as np
+    per_row = _vector_errors(got, want).max(axis=(1, 2))
+    print(f"planted fault, {name}: worst relative error per row "
+          f"{np.array2string(per_row, precision=3)} (bound {rtol:g})")
+    missed = [r for r in rows if not per_row[r] > rtol]
+    check(not missed, f"{name}: a planted fault passed the comparison in "
+                      f"rows {missed}")
+
+
+def _compare_relative(name, got, want, rtol, why):
+    """Elementwise |got − want| ≤ rtol·|want|."""
+    import numpy as np
+    got = np.asarray(got, np.float32)
+    want = np.asarray(want, np.float32)
+    worst = (np.abs(got - want) / np.abs(want)).max()
+    print(f"compare {name}: worst relative error {worst:.3e}, "
+          f"{worst / rtol:.3f} of the bound ({rtol:g}: {why})")
     check(np.isfinite(got).all(), f"{name}: non-finite output")
-    check((err <= bound).all(), f"{name}: outside tolerance")
+    check(worst <= rtol, f"{name}: outside tolerance")
 
 
-# Tolerances.  References run at full fp32 matmul precision.  The kernels
-# compute in fp32 but their dots may run as one bf16 pass (Mosaic's default
-# precision), and every output is rounded to bf16 (2^-8 relative spacing).
-_BF16_WHY = ("bf16 output rounding in both forms, plus the kernel's softmax "
-             "weights rounding to bf16 (2^-9) in its P·V dot; |V| ≲ 4.5")
-_INT8_WHY = ("as bf16, plus dequantized K/V (int8 × bf16 scale, ~15 "
-             "significant bits) rounding to bf16 in the kernel's dots, "
-             "which moves scores of size ~3 by ~1e-2")
-TOL = {False: (1e-2, 1e-2, _BF16_WHY), True: (3e-2, 1e-2, _INT8_WHY)}
+# Bounds on each output vector's relative error (‖Δ‖₂/‖ref‖₂ over the head
+# dim).  References run at full fp32 matmul precision.  Each form rounds its
+# output to bf16, at most 2^-8 of each element, so the pair may differ by
+# 2^-7 ≈ 7.8e-3; the kernel also rounds its softmax weights to bf16 before
+# the P·V dot (~2^-9).  The bounds take twice that, and stay a tenth of the
+# smallest error a planted fault gives (one wrong 16-token page per row:
+# 0.32 to 0.95 per row in interpret mode on a CPU).
+_BF16_WHY = ("bf16 output rounding in both forms (≤ 7.8e-3) plus the "
+             "kernel's bf16 softmax weights in its P·V dot, doubled")
+_INT8_WHY = ("as bf16, plus dequantized K/V (int8 × bf16 scale) rounded to "
+             "bf16 in the kernel's dots, ~2^-8 more on scores and values")
+RTOL = {False: (2e-2, _BF16_WHY), True: (3e-2, _INT8_WHY)}
 
 
-def compare_paged_attention(cfg, quantized: bool, seed: int):
-    """One paged decode step and one paged prefill chunk: dispatch's route
-    (the Pallas kernels on TPU) against the gather + chunked-XLA form on the
-    same pool."""
+def compare_paged_attention(cfg, quantized: bool, seed: int,
+                            path: str = "pallas"):
+    """One paged decode step over ragged rows (1 to ``SLOT_LEN`` tokens) and
+    one paged prefill chunk (the last ``CHUNK`` tokens of a full row):
+    dispatch's route (the Pallas kernels on TPU; ``path`` names it) against
+    the gather + chunked-XLA form on the same pool.  Each comparison is
+    then made again with a planted fault in the kernel's block table, which
+    it must catch."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -244,37 +281,47 @@ def compare_paged_attention(cfg, quantized: bool, seed: int):
 
     (k, v), (ks, vs), tables, rng = _paged_operands(cfg, quantized, seed)
     hq, d = cfg.num_heads, cfg.resolved_head_dim
-    atol, rtol, why = TOL[quantized]
+    rtol, why = RTOL[quantized]
     tag = "int8" if quantized else "bf16"
     obs_kernels.reset()
 
-    lens = jnp.asarray(np.linspace(1, SLOT_LEN, SLOTS), jnp.int32)  # ragged
+    lens = np.linspace(1, SLOT_LEN, SLOTS).astype(np.int32)    # ragged
     q = jnp.asarray(rng.standard_normal((SLOTS, 1, hq, d)), jnp.bfloat16)
-    kw = dict(q_offset=lens - 1, kv_valid_len=lens, block_tables=tables,
+    kw = dict(q_offset=jnp.asarray(lens - 1), kv_valid_len=jnp.asarray(lens),
               scale=None, k_scale=ks, v_scale=vs)
-    got = dispatch.sdpa(cfg, q, k, v, causal=False, decode=True, **kw)
+    got = dispatch.sdpa(cfg, q, k, v, causal=False, decode=True,
+                        block_tables=tables, **kw)
     with jax.default_matmul_precision("float32"):
         want = dispatch.implementation("paged_decode_attention",
-                                       dispatch.PATH_XLA)(cfg, q, k, v, **kw)
-    _compare(f"paged decode ({tag}, {SLOTS} rows)", got, want, atol, rtol,
-             why)
+                                       dispatch.PATH_XLA)(
+            cfg, q, k, v, block_tables=tables, **kw)
+    name = f"paged decode ({tag}, rows of {lens.tolist()} tokens)"
+    _compare_vectors(name, got, want, rtol, why)
+    bad = dispatch.sdpa(cfg, q, k, v, causal=False, decode=True,
+                        block_tables=_drop_last_page(tables, lens), **kw)
+    _check_fault_caught(name, bad, want, rtol,
+                        [r for r in range(SLOTS) if lens[r] > BLOCK])
 
-    off, tq = PREFIX, CHUNK
+    off, tq = SLOT_LEN - CHUNK, CHUNK
     qp = jnp.asarray(rng.standard_normal((1, tq, hq, d)), jnp.bfloat16)
     kw = dict(q_offset=jnp.asarray([off], jnp.int32),
-              kv_valid_len=jnp.asarray([off + tq], jnp.int32),
-              block_tables=tables[:1], scale=None,
+              kv_valid_len=jnp.asarray([SLOT_LEN], jnp.int32), scale=None,
               k_scale=ks, v_scale=vs)
-    got = dispatch.sdpa(cfg, qp, k, v, causal=True, **kw)
+    got = dispatch.sdpa(cfg, qp, k, v, causal=True, block_tables=tables[:1],
+                        **kw)
     with jax.default_matmul_precision("float32"):
         want = dispatch.implementation("paged_attention", dispatch.PATH_XLA)(
-            cfg, qp, k, v, causal=True, **kw)
-    _compare(f"paged prefill chunk ({tag}, {tq} tokens at offset {off})",
-             got, want, atol, rtol, why)
+            cfg, qp, k, v, causal=True, block_tables=tables[:1], **kw)
+    name = f"paged prefill chunk ({tag}, {tq} tokens at offset {off})"
+    _compare_vectors(name, got, want, rtol, why)
+    bad = dispatch.sdpa(cfg, qp, k, v, causal=True,
+                        block_tables=_drop_last_page(tables[:1], [SLOT_LEN]),
+                        **kw)
+    _check_fault_caught(name, bad, want, rtol, [0])
     paths = obs_kernels.snapshot()["paths"]
     for op in ("paged_decode_attention", "paged_attention"):
-        check(paths.get(op, {}).get("path") == dispatch.PATH_PALLAS,
-              f"{tag} {op} did not take the Pallas kernel: {paths.get(op)}")
+        check(paths.get(op, {}).get("path") == path,
+              f"{tag} {op} did not take the {path} kernel: {paths.get(op)}")
 
 
 def compare_softmax_topk(cfg, seed: int):
@@ -293,11 +340,11 @@ def compare_softmax_topk(cfg, seed: int):
     # both fp32: exp may differ by a few ulps between the kernel and XLA,
     # and the 49152-term normalizer sums in a different order (~1e-6
     # relative expected; 1e-4 leaves headroom)
-    _compare("softmax_topk values", out.values, want_vals, 0.0, 1e-4,
-             "fp32 exp ulps + normalizer summation order")
-    _compare("softmax_topk lse", out.logsumexp,
-             jax.scipy.special.logsumexp(x, axis=-1), 0.0, 1e-5,
-             "fp32 exp ulps + summation order, relative to lse ≈ 19")
+    _compare_relative("softmax_topk values", out.values, want_vals, 1e-4,
+                      "fp32 exp ulps + normalizer summation order")
+    _compare_relative("softmax_topk lse", out.logsumexp,
+                      jax.scipy.special.logsumexp(x, axis=-1), 1e-5,
+                      "fp32 exp ulps + summation order, lse ≈ 19")
     xs = np.asarray(x)
     got_logits = np.take_along_axis(xs, np.asarray(out.indices), -1)
     want_logits = np.take_along_axis(xs, np.asarray(want_idx), -1)
@@ -312,17 +359,26 @@ def compare_softmax_topk(cfg, seed: int):
 TRAIN_STEPS = 3
 TRAIN_SEQ = 256
 TRAIN_BATCH = 8
-LOSS_TOL = 5e-3        # tests/test_distributed.py's sharded-vs-single bound
+# Bound on |Δloss| between a mesh and one device, each step from the same
+# state.  fp32 weights: tests/test_distributed.py's 5e-3, absolute.  bf16
+# weights (as served): the row-parallel matmuls of the model axis round
+# each partial sum to bf16 before adding them, which alone moves the loss
+# by ~5e-5 of itself (CPU, 8 layers: 5.0e-5 in bf16, 8.8e-8 in fp32), so
+# the bound is relative: 2^-10 of the loss.  Dropping or doubling one model
+# shard's partial sum in one layer of eight moves it by 2.2% / 4.8% (CPU).
+LOSS_BOUND = {"float32": lambda loss: 5e-3,
+              "bfloat16": lambda loss: abs(loss) * 2.0 ** -10}
 
 
-def four_chip_train(cfg, seed: int, clock):
-    """Each of ``TRAIN_STEPS`` steps runs on one device and, from the same
-    state, sharded on each mesh the way ``repro.launch.train`` shards it
-    (params by the sharding rules, each batch over the data axes); the
-    losses must agree within ``LOSS_TOL``.  Steps start from a common state
-    because whole trajectories part: with bf16 weights, AdamW's early
-    updates (≈ lr·sign(g)) turn last-bit gradient differences into
-    different weights."""
+def train_losses(cfg, seed: int, mesh_dims):
+    """Losses of ``TRAIN_STEPS`` train steps on one device, and, from the
+    same state at each step, sharded on each mesh of ``mesh_dims`` the way
+    ``repro.launch.train`` shards it (params by the sharding rules, each
+    batch over the data axes).  Steps start from a common state because
+    whole trajectories part: with bf16 weights, AdamW's early updates
+    (≈ lr·sign(g)) turn last-bit gradient differences into different
+    weights.  Between steps the state waits on the host, so one device
+    never holds two copies of it."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
@@ -330,49 +386,72 @@ def four_chip_train(cfg, seed: int, clock):
     from repro.configs.base import OptimizerConfig, ParallelConfig, RunConfig
     from repro.data.synthetic import SyntheticConfig, SyntheticDataset
     from repro.distributed import context, sharding
+    from repro.models.params import init_params
     from repro.optim import adamw
     from repro.training.train_step import make_train_step
 
     run = RunConfig(model=cfg, optimizer=OptimizerConfig(
         lr=1e-3, warmup_steps=0, schedule="constant"),
         parallel=ParallelConfig(grad_reduce_dtype="float32"))
-    params, axes = init_params(cfg, seed)
-    opt = adamw.init(params)
+    params, axes = init_params(cfg, jax.random.PRNGKey(seed))
+    state = jax.device_get((params, adamw.init(params)))
+    del params
     ds = SyntheticDataset(SyntheticConfig(
         vocab_size=cfg.vocab_size, seq_len=TRAIN_SEQ,
         global_batch=TRAIN_BATCH, seed=seed))
-    n = len(jax.devices())
     meshes = []
-    for name, dims in ((f"data={n}", (n, 1)),
-                       (f"data={n // 2} x model=2", (n // 2, 2))):
+    for dims in mesh_dims:
         mesh = compat.make_mesh(dims, ("data", "model"))
         par = sharding.derive_parallel(cfg, mesh, run.parallel)
-        meshes.append((name, mesh, par,
+        meshes.append((f"data={dims[0]} x model={dims[1]}", mesh, par,
                        sharding.param_sharding(axes, cfg, par, mesh),
                        compat.named_sharding(mesh, P(par.data_axes)),
                        jax.jit(make_train_step(run), donate_argnums=(0, 1))))
     one = jax.jit(make_train_step(run), donate_argnums=(0, 1))
     ref, got = [], {name: [] for name, *_ in meshes}
-    c0 = clock.seconds
     for i in range(TRAIN_STEPS):
         batch = compat.tree_map(jnp.asarray, ds.batch(i))
-        host_params, host_opt = jax.device_get((params, opt))
         for name, mesh, par, p_sh, on_data, step in meshes:
             with mesh, context.use(context.ShardContext(mesh=mesh, par=par)):
-                _, _, m = step(jax.device_put(host_params, p_sh), host_opt,
+                _, _, m = step(jax.device_put(state[0], p_sh), state[1],
                                jax.device_put(batch, on_data))
             got[name].append(float(m["loss"]))
-        params, opt, m = one(params, opt, batch)
+        params, opt, m = one(*jax.device_put(state), batch)
         ref.append(float(m["loss"]))
-    print(f"[train] one device: losses {ref} "
-          f"({clock.seconds - c0:.1f}s compiling, all meshes)")
+        state = jax.device_get((params, opt))
+        del params, opt
+    return ref, got
+
+
+def four_chip_train(cfg, seed: int, clock):
+    """The sharded train step against one device on the launcher's default
+    mesh (all chips on ``data``) and on the 2×2 ``data``×``model`` mesh,
+    with fp32 weights (the test's bound) and with bf16 weights (as served).
+    fp32 dots run at full precision, so that run is the arithmetic with
+    next to no rounding."""
+    import contextlib
+    import jax
+
+    n = len(jax.devices())
     bad = []
-    for name, losses in got.items():
-        worst = max(abs(a - b) for a, b in zip(losses, ref))
-        print(f"[train] mesh {name}: losses {losses}, max |Δloss| vs one "
-              f"device from the same state {worst:.3e} (bound {LOSS_TOL:g})")
-        if worst >= LOSS_TOL:
-            bad.append(f"{name}: {worst:.3e}")
+    for dtype in ("float32", "bfloat16"):
+        c0 = clock.seconds
+        prec = (jax.default_matmul_precision("float32")
+                if dtype == "float32" else contextlib.nullcontext())
+        with prec:
+            ref, got = train_losses(cfg.replace(dtype=dtype), seed,
+                                    ((n, 1), (n // 2, 2)))
+        print(f"[train {dtype}] one device: losses {ref} "
+              f"({clock.seconds - c0:.1f}s compiling, all meshes)")
+        for name, losses in got.items():
+            gaps = [abs(a - b) for a, b in zip(losses, ref)]
+            bounds = [LOSS_BOUND[dtype](b) for b in ref]
+            print(f"[train {dtype}] mesh {name}: losses {losses}, |Δloss| "
+                  f"vs one device from the same state "
+                  f"{[f'{g:.3e}' for g in gaps]} "
+                  f"(bounds {[f'{b:.3e}' for b in bounds]})")
+            if any(g >= b for g, b in zip(gaps, bounds)):
+                bad.append(f"{dtype} {name}: {max(gaps):.3e}")
     check(not bad, f"sharded losses differ from one device: {bad}")
 
 
@@ -415,6 +494,7 @@ def main(argv=None) -> int:
 
     import repro.configs as configs
     from repro import cache_dirs
+    from repro.models.params import init_params
     from repro.obs import clock as obs_clock
 
     print(f"device: {devices[0].device_kind} ×{len(devices)}, jax "
@@ -434,7 +514,8 @@ def main(argv=None) -> int:
                args.seed, clock, failures=failures)
     else:
         c0, t0 = clock.seconds, obs_clock.monotonic()
-        params, _ = init_params(cfg, args.seed)
+        params, _ = init_params(cfg, jax.random.PRNGKey(args.seed))
+        jax.block_until_ready(params)
         print(f"init: {obs_clock.monotonic() - t0:.2f}s "
               f"({clock.seconds - c0:.2f}s compiling)")
         vocab = cfg.real_vocab_size or cfg.vocab_size

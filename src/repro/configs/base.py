@@ -99,10 +99,12 @@ class ModelConfig:
     # §Perf levers (baseline off; flipped by the hillclimb)
     attn_causal_blocks: int = 0   # >1: causal chunk skipping (q-block unroll)
     kv_cache_dtype: str = ""      # "" = model dtype; "int8" = quantized cache
-    # Pallas preference for contiguous attention (training / unpaged serving;
-    # on TPU it swaps in kernels/).  Paged attention and softmax_topk take
-    # the native kernels on TPU regardless; off the chip this asks for
-    # interpret mode (kernel tests).
+    # Asks for the Pallas kernels where routing would not take them.
+    # Contiguous attention (training / unpaged serving): the kernels on any
+    # backend, interpret mode off the chip.  Paged attention (decode and
+    # prefill) and softmax_topk take the native kernels wherever Pallas
+    # compiles, set or not; off the chip this runs them in interpret mode,
+    # which is all it does for them (kernel tests).
     use_pallas: bool = False
     # remat: "full" = recompute everything inside a block (layer inputs kept
     # by the scan carry — MaxText-style default for big models);

@@ -20,10 +20,11 @@ of crashing — the portability counterpart of the compat import shims.
 Block sizes are not hard-coded either: ``block_decision`` runs a lightweight
 autotune sweep over the ⊕-tree shape (``online_normalizer_blocked``'s
 ``block`` knob — §3.1 of the paper: any reduction tree gives the same
-``(m, d)``, so the sweep is free to pick the fastest) and caches the winner
-per (backend, vocab, dtype).  The second call for the same key is a pure
-cache hit.  Decisions persist to an on-disk JSON cache inside the checkout
-(``repro.cache_dirs.AUTOTUNE_CACHE_PATH``; overridable via
+``(m, d)``, so the sweep is free to pick the fastest; a candidate displaces
+the registry default only when it is clearly faster, see ``_pick``) and
+caches the winner per (backend, vocab, dtype).  The second call for the
+same key is a pure cache hit.  Decisions persist to an on-disk JSON cache
+inside the checkout (``repro.cache_dirs.AUTOTUNE_CACHE_PATH``; overridable via
 ``REPRO_AUTOTUNE_CACHE``, set it empty to disable) loaded at import, so a
 serving restart skips the sweep entirely.
 
@@ -54,6 +55,7 @@ import concurrent.futures
 import functools
 import json
 import os
+import statistics
 from dataclasses import asdict, dataclass
 from typing import Callable
 
@@ -126,8 +128,10 @@ def lookup(op: str, prefer_pallas: bool = False) -> tuple[str, Callable]:
 # Block-size autotune: per-(backend, vocab, dtype), ⊕-tree-shape sweep.
 # ---------------------------------------------------------------------------
 BLOCK_CANDIDATES = (256, 512, 1024, 2048, 4096)
+BLOCK_DEFAULT = 2048     # the incumbent vocab block (clamped to the vocab)
 _TUNE_ROWS = 4           # sample batch height: enough to engage vectorization
-_TUNE_REPS = 3
+_TUNE_REPS = 7           # timed calls per candidate, round-robin
+_TUNE_MARGIN = 0.10      # share by which a challenger must beat the incumbent
 
 _BLOCK_CACHE: dict[tuple[str, int, str], "BlockDecision"] = {}
 _TILE_CACHE: dict[tuple, "TileDecision"] = {}
@@ -156,7 +160,7 @@ class BlockDecision:
     vocab: int
     dtype: str
     block: int                       # winning ⊕-tree leaf width
-    timings_us: tuple                # ((candidate, best_of_reps_us), ...)
+    timings_us: tuple                # ((candidate, median_us), ...)
 
     def to_dict(self) -> dict:
         return asdict(self)
@@ -170,7 +174,7 @@ class TileDecision:
     head_dim: int
     dtype: str
     tiles: dict                      # resolved {"bq": ..} / {"bk": ..}
-    timings_us: tuple                # ((candidate, best_of_reps_us), ...) or ()
+    timings_us: tuple                # ((candidate, median_us), ...) or ()
 
     def to_dict(self) -> dict:
         return asdict(self)
@@ -181,7 +185,7 @@ class TileDecision:
 # the sweep.  Best-effort — an unwritable/corrupt cache never breaks dispatch.
 # ---------------------------------------------------------------------------
 AUTOTUNE_CACHE_ENV = "REPRO_AUTOTUNE_CACHE"
-CACHE_SCHEMA_VERSION = 1       # stamped into every payload; mismatch → re-sweep
+CACHE_SCHEMA_VERSION = 2       # stamped into every payload; mismatch → re-sweep
 _DEFAULT_CACHE_PATH = cache_dirs.AUTOTUNE_CACHE_PATH
 _CACHE_WARNED = False          # warn once per process, then stay quiet
 
@@ -318,7 +322,37 @@ def _outside_trace(fn: Callable):
         return pool.submit(fn).result()
 
 
-def _time_blocked(x: Array, block: int) -> float:
+def _timed_us(runners: dict) -> tuple:
+    """Median µs of each zero-argument runner over ``_TUNE_REPS`` calls,
+    after one warm-up call each (compile).  Calls go round-robin across the
+    candidates, so drift in the host's load hits every candidate alike."""
+    for run in runners.values():
+        run()
+    samples = {c: [] for c in runners}
+    for _ in range(_TUNE_REPS):
+        for c, run in runners.items():
+            t0 = obs_clock.perf_counter()
+            run()
+            samples[c].append(obs_clock.perf_counter() - t0)
+    return tuple((c, round(statistics.median(t) * 1e6, 2))
+                 for c, t in samples.items())
+
+
+def _pick(timings: tuple, incumbent):
+    """The fastest candidate, unless the incumbent's median is within
+    ``_TUNE_MARGIN`` of it.  Host-clock timings of these short calls spread
+    by about that much between runs (a TPU v5e's vocab-block sweep ranked
+    its candidates differently in each of three runs), and a pick made by
+    noise would differ between restarts and between checkouts."""
+    best, best_us = min(timings, key=lambda t: t[1])
+    incumbent_us = dict(timings).get(incumbent)
+    if incumbent_us is not None \
+            and best_us > incumbent_us * (1 - _TUNE_MARGIN):
+        return incumbent
+    return best
+
+
+def _blocked_runner(x: Array, block: int) -> Callable:
     if compat.pallas_native():
         # time the thing being configured: the Pallas kernel at this tile
         # width (an XLA-scan proxy would not rank Mosaic VMEM tiles)
@@ -327,13 +361,7 @@ def _time_blocked(x: Array, block: int) -> float:
     else:
         fn = jax.jit(functools.partial(core.online_normalizer_blocked,
                                        block=block))
-    jax.block_until_ready(fn(x))                       # compile + warm
-    best = float("inf")
-    for _ in range(_TUNE_REPS):
-        t0 = obs_clock.perf_counter()
-        jax.block_until_ready(fn(x))
-        best = min(best, obs_clock.perf_counter() - t0)
-    return best * 1e6
+    return lambda: jax.block_until_ready(fn(x))
 
 
 def block_decision(vocab: int, dtype=jnp.float32) -> BlockDecision:
@@ -351,10 +379,10 @@ def block_decision(vocab: int, dtype=jnp.float32) -> BlockDecision:
         cands = sorted({min(b, vocab) for b in BLOCK_CANDIDATES})
         x = (jnp.arange(_TUNE_ROWS * vocab, dtype=jnp.float32) % 251.0
              ).reshape(_TUNE_ROWS, vocab).astype(dtype)
-        return tuple((b, round(_time_blocked(x, b), 2)) for b in cands)
+        return _timed_us({b: _blocked_runner(x, b) for b in cands})
 
     timings = _outside_trace(sweep)
-    winner = min(timings, key=lambda t: t[1])[0]
+    winner = _pick(timings, min(BLOCK_DEFAULT, vocab))
     decision = BlockDecision(backend=key[0], vocab=vocab, dtype=key[2],
                              block=winner, timings_us=timings)
     _BLOCK_CACHE[key] = decision
@@ -367,24 +395,18 @@ def tuned_block(vocab: int, dtype=jnp.float32) -> int:
     return block_decision(vocab, dtype).block
 
 
-def _time_decode_bk(kv_len: int, head_dim: int, dtype, bk: int) -> float:
+def _decode_runner(kv_len: int, head_dim: int, dtype, bk: int) -> Callable:
     from repro.kernels import ops
     q = jnp.ones((_TUNE_ROWS, 8, head_dim), dtype)
     kc = jnp.ones((_TUNE_ROWS, kv_len, 8, head_dim), dtype)
     vlen = jnp.full((_TUNE_ROWS,), kv_len, jnp.int32)
     fn = jax.jit(functools.partial(ops.flash_decode, bk=bk))
-    jax.block_until_ready(fn(q, kc, kc, vlen))
-    best = float("inf")
-    for _ in range(_TUNE_REPS):
-        t0 = obs_clock.perf_counter()
-        jax.block_until_ready(fn(q, kc, kc, vlen))
-        best = min(best, obs_clock.perf_counter() - t0)
-    return best * 1e6
+    return lambda: jax.block_until_ready(fn(q, kc, kc, vlen))
 
 
-def _time_prefill_tiles(op: str, kv_len: int, head_dim: int, dtype,
-                        bq: int, bk: int) -> float:
-    """Time one (bq, bk) candidate of a prefill-form flash kernel.
+def _prefill_runner(op: str, kv_len: int, head_dim: int, dtype,
+                    bq: int, bk: int) -> Callable:
+    """One (bq, bk) candidate of a prefill-form flash kernel, ready to time.
 
     ``flash_attention`` times the fresh self-attention form;
     ``flash_attention_offset`` the cached-chunk form (queries offset halfway
@@ -419,13 +441,7 @@ def _time_prefill_tiles(op: str, kv_len: int, head_dim: int, dtype,
         kv = jnp.ones((_TUNE_ROWS, kv_len, hkv, head_dim), dtype)
         fn = jax.jit(functools.partial(ops.flash_attention, bq=bq, bk=bk))
         args = (q, kv, kv)
-    jax.block_until_ready(fn(*args))
-    best = float("inf")
-    for _ in range(_TUNE_REPS):
-        t0 = obs_clock.perf_counter()
-        jax.block_until_ready(fn(*args))
-        best = min(best, obs_clock.perf_counter() - t0)
-    return best * 1e6
+    return lambda: jax.block_until_ready(fn(*args))
 
 
 _PREFILL_TILE_OPS = ("flash_attention", "flash_attention_offset",
@@ -456,10 +472,9 @@ def attention_tiles(op: str, *, kv_len: int, head_dim: int,
         _SWEEPS += 1
         cands = sorted({min(b, kv_len) for b in DECODE_BK_CANDIDATES
                         if kv_len % min(b, kv_len) == 0})
-        timings = _outside_trace(lambda: tuple(
-            (b, round(_time_decode_bk(kv_len, head_dim, dtype, b), 2))
-            for b in cands))
-        defaults["bk"] = min(timings, key=lambda t: t[1])[0]
+        timings = _outside_trace(lambda: _timed_us(
+            {b: _decode_runner(kv_len, head_dim, dtype, b) for b in cands}))
+        defaults["bk"] = _pick(timings, min(defaults["bk"], kv_len))
     elif op in _PREFILL_TILE_OPS and compat.pallas_native():
         _SWEEPS += 1
         bqs = sorted({min(c, kv_len) for c in PREFILL_TILE_CANDIDATES})
@@ -469,12 +484,12 @@ def attention_tiles(op: str, *, kv_len: int, head_dim: int,
             bks = sorted({min(c, kv_len) for c in PREFILL_TILE_CANDIDATES
                           if kv_len % min(c, kv_len) == 0})
             cands = [(bq, bk) for bq in bqs for bk in bks]
-        timings = _outside_trace(lambda: tuple(
-            ((bq, bk),
-             round(_time_prefill_tiles(op, kv_len, head_dim, dtype, bq, bk),
-                   2))
-            for bq, bk in cands))
-        best_bq, best_bk = min(timings, key=lambda t: t[1])[0]
+        timings = _outside_trace(lambda: _timed_us(
+            {(bq, bk): _prefill_runner(op, kv_len, head_dim, dtype, bq, bk)
+             for bq, bk in cands}))
+        incumbent = (min(defaults["bq"], kv_len),
+                     min(defaults.get("bk", 0), kv_len))
+        best_bq, best_bk = _pick(timings, incumbent)
         defaults["bq"] = best_bq
         if "bk" in defaults:
             defaults["bk"] = best_bk
@@ -675,7 +690,7 @@ def _paged_attention_xla(cfg, q, k, v, *, causal, q_offset, kv_valid_len,
         chunk_size=cfg.attn_chunk, scale=scale)
 
 
-@register("paged_decode_attention", PATH_PALLAS)
+@register("paged_decode_attention", PATH_PALLAS, PATH_PALLAS_INTERPRET)
 def _paged_decode_attention_pallas(cfg, q, k, v, *, q_offset, kv_valid_len,
                                    block_tables, scale, k_scale=None,
                                    v_scale=None):
@@ -708,9 +723,9 @@ def _paged_sdpa(cfg, q, k, v, *, causal, q_offset, kv_valid_len, scale,
     """Routing for block-table attention: ``select_path``'s own policy.
 
     Decode and prefill take the Pallas paged kernels wherever Pallas
-    compiles natively.  Off the chip, ``cfg.use_pallas`` asks for the
-    prefill kernel in interpret mode (decode has no interpret entry);
-    otherwise the gather + chunked XLA form runs.  Shapes the kernels cannot
+    compiles natively.  Off the chip, ``cfg.use_pallas`` asks for both
+    kernels in interpret mode; otherwise the gather + chunked XLA form
+    runs.  Shapes the kernels cannot
     represent (custom scale, value-dim ≠ key-dim) always take the XLA form.
     Quantized pools (``k_scale``/``v_scale`` pages set) ride the same
     routing — every path dequantizes after its gather.  Paged serving is

@@ -34,7 +34,8 @@ import numpy as np
 
 import repro.configs as configs
 from repro import cache_dirs
-from repro.models import encdec, layers as L, transformer
+from repro.models import transformer
+from repro.models.params import init_params
 from repro.obs import clock as obs_clock
 from repro.obs import kernels as obs_kernels
 from repro.obs import metrics as obs_metrics
@@ -347,8 +348,7 @@ def main(argv=None):
         raise SystemExit("--paged requires --continuous (the lockstep "
                          "baseline keeps its contiguous cache)")
 
-    init_fn = encdec.init if family.kind == "encdec" else transformer.init
-    params, _ = L.split_params(init_fn(jax.random.PRNGKey(0), cfg))
+    params, _ = init_params(cfg, jax.random.PRNGKey(0))
     if args.continuous:
         return _continuous(args, cfg, params)
     return _lockstep(args, cfg, params)

@@ -17,7 +17,8 @@ import jax.numpy as jnp
 from repro import compat
 from repro.configs.base import RunConfig
 from repro.distributed import compression
-from repro.models import encdec, layers as L, transformer
+from repro.models import encdec, transformer
+from repro.models.params import init_params
 from repro.optim import adamw
 
 PyTree = Any
@@ -67,9 +68,7 @@ def make_train_step(run: RunConfig) -> Callable:
 def init_state(run: RunConfig, rng) -> tuple[PyTree, PyTree, PyTree]:
     """Returns (params, opt_state, axes_tree)."""
     cfg = run.model
-    init_fn = encdec.init if cfg.family == "encdec" else transformer.init
-    boxed = init_fn(rng, cfg)
-    params, axes = L.split_params(boxed)
+    params, axes = init_params(cfg, rng)
     opt_state = adamw.init(params)
     return params, opt_state, axes
 

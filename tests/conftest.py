@@ -8,6 +8,7 @@ from __future__ import annotations
 
 import os
 import sys
+import tempfile
 
 _TESTS_DIR = os.path.dirname(os.path.abspath(__file__))
 _REPO = os.path.dirname(_TESTS_DIR)
@@ -24,9 +25,18 @@ for p in (_SRC, _TESTS_DIR):
 # file (unless the caller pinned one) so the suite neither pollutes nor reads
 # the checkout's own .cache/autotune.json.
 if "REPRO_AUTOTUNE_CACHE" not in os.environ:
-    import tempfile
     os.environ["REPRO_AUTOTUNE_CACHE"] = os.path.join(
         tempfile.gettempdir(), f"repro_autotune_test_{os.getpid()}.json")
+
+# Hermetic compile cache for the entry points the suite runs as subprocesses
+# (serve, benchmarks/run.py): they turn on JAX's persistent cache, whose
+# default is the checkout's .cache/jax.  Children inherit a per-run temp
+# dir instead; this process keeps the cache off, as it would be unset.
+if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = os.path.join(
+        tempfile.gettempdir(), f"repro_jax_cache_test_{os.getpid()}")
+    import jax
+    jax.config.update("jax_compilation_cache_dir", None)
 
 
 def pytest_report_header(config):

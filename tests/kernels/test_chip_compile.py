@@ -119,7 +119,7 @@ def test_paged_prefill_compiles(one_chip, no_compile_cache, quantized, tq):
                                        dispatch.PREFILL_TILE_CANDIDATES}))
 def test_paged_prefill_sweep_candidates_compile(one_chip, no_compile_cache,
                                                 bq):
-    """The shapes ``dispatch._time_prefill_tiles`` builds for the paged
+    """The shapes ``dispatch._prefill_runner`` builds for the paged
     ``bq`` sweep at this slot length (synthetic 8-head pool of
     ``_PAGED_TUNE_BLOCK``-token pages)."""
     sh = one_chip
@@ -144,7 +144,7 @@ def test_softmax_topk_compiles(one_chip, no_compile_cache, v_blk):
 
 @pytest.mark.parametrize("v_blk", dispatch.BLOCK_CANDIDATES)
 def test_online_normalizer_compiles(one_chip, no_compile_cache, v_blk):
-    """The vocab-block sweep's timed kernel (``dispatch._time_blocked``)."""
+    """The vocab-block sweep's timed kernel (``dispatch._blocked_runner``)."""
     rows = dispatch._TUNE_ROWS
     _assert_kernel(online_normalizer_pallas,
                    _spec((rows, VOCAB), jnp.float32, one_chip), r_blk=rows,

@@ -376,6 +376,21 @@ def test_autotune_caches_block_decision():
     assert dispatch.autotune_stats() == {"sweeps": 3, "entries": 3}
 
 
+def test_autotune_pick_keeps_incumbent_within_margin():
+    """A challenger displaces the registry default only when its median is
+    more than ``_TUNE_MARGIN`` faster; timing noise alone must not move a
+    tile choice between runs."""
+    m = dispatch._TUNE_MARGIN
+    near = ((256, 100.0), (512, 100.0 * (1 - m / 2)), (2048, 100.0))
+    assert dispatch._pick(near, 2048) == 2048
+    clear = ((256, 100.0), (512, 100.0 * (1 - 2 * m)), (2048, 100.0))
+    assert dispatch._pick(clear, 2048) == 512
+    # an incumbent outside the candidates (clamped away) cannot hold
+    assert dispatch._pick(near, 4096) == 512
+    assert dispatch._pick((((256, 0), 9.0), ((512, 0), 9.5)), (512, 0)) \
+        == (512, 0)
+
+
 def test_autotune_sweep_inside_jit_trace_measures_execution():
     """The serving step jits decode, so the first sweep can fire during an
     outer trace; the sweep must still run concretely (a traced sweep would
