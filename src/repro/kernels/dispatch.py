@@ -42,6 +42,12 @@ cache and reuses the chunked online form.  Quantized (int8) pools carry
 AFTER the gather — scale pages ride the same block table and the same
 clamped page index, so the pool lifecycle never sees fp data.
 
+Each public op (``online_softmax``, ``softmax_topk``, ``sdpa``) runs under
+a ``jax.named_scope`` named ``kernel.<op>`` — for ``sdpa`` by route:
+``kernel.paged_decode``, ``kernel.paged_prefill``, ``kernel.decode``,
+``kernel.attention`` — whichever implementation it picks, so a device
+trace's name stacks attribute op time to the kernel entry that issued it.
+
 Reduced-precision softmax forms (PAPERS.md 2201.04562 / 2111.10770) are
 registry ops too — ``online_softmax_bf16`` (bf16 normalizer accumulator)
 and ``online_softmax_exp2`` (exp2-based exponentials) — selected by a
@@ -786,11 +792,11 @@ def online_softmax(x: Array) -> Array:
     """Softmax over the last axis via the best path for this backend,
     honoring the process softmax-form preference (``set_softmax_form`` /
     ``REPRO_SOFTMAX_FORM``)."""
-    if _SOFTMAX_FORM != "exact":
-        _, fn = lookup(f"online_softmax_{_SOFTMAX_FORM}")
+    op = ("online_softmax" if _SOFTMAX_FORM == "exact"
+          else f"online_softmax_{_SOFTMAX_FORM}")
+    _, fn = lookup(op)
+    with jax.named_scope("kernel.online_softmax"):
         return fn(x)
-    _, fn = lookup("online_softmax")
-    return fn(x)
 
 
 def softmax_topk(x: Array, k: int,
@@ -806,7 +812,8 @@ def softmax_topk(x: Array, k: int,
     """
     del differentiable
     _, fn = lookup("softmax_topk")
-    return fn(x, k)
+    with jax.named_scope("kernel.softmax_topk"):
+        return fn(x, k)
 
 
 def sdpa(cfg, q, k, v, *, causal, q_offset, kv_valid_len, scale=None,
@@ -859,10 +866,23 @@ def sdpa(cfg, q, k, v, *, causal, q_offset, kv_valid_len, scale=None,
         registry ops with the gather + chunked-XLA fallback off-TPU.
     """
     if block_tables is not None:
-        return _paged_sdpa(cfg, q, k, v, causal=causal, q_offset=q_offset,
-                           kv_valid_len=kv_valid_len, scale=scale,
-                           decode=decode, block_tables=block_tables,
-                           k_scale=k_scale, v_scale=v_scale)
+        with jax.named_scope("kernel.paged_decode" if decode
+                             else "kernel.paged_prefill"):
+            return _paged_sdpa(cfg, q, k, v, causal=causal,
+                               q_offset=q_offset, kv_valid_len=kv_valid_len,
+                               scale=scale, decode=decode,
+                               block_tables=block_tables, k_scale=k_scale,
+                               v_scale=v_scale)
+    with jax.named_scope("kernel.decode" if decode else "kernel.attention"):
+        return _contiguous_sdpa(cfg, q, k, v, causal=causal,
+                                q_offset=q_offset, kv_valid_len=kv_valid_len,
+                                scale=scale, decode=decode, k_scale=k_scale,
+                                v_scale=v_scale)
+
+
+def _contiguous_sdpa(cfg, q, k, v, *, causal, q_offset, kv_valid_len, scale,
+                     decode, k_scale, v_scale):
+    """``sdpa`` over contiguous K/V (no block tables)."""
     from repro.distributed import context
     ctx = context.get()
     if decode and ctx is not None:

@@ -17,7 +17,10 @@ the urgent class; the report then adds p95-by-class, SLO attainment, and
 preemption/swap counts.  ``--replicas N`` serves the same workload through
 ``repro.serving.router.ReplicaRouter`` over N engine replicas —
 prefix-affinity routed (``--no-affinity`` for round-robin), with admission
-backpressure and a globally merged report.  Without ``--continuous`` the original
+backpressure and a globally merged report.  ``--profile DIR`` records a JAX
+profiler trace of the serve loop: the scheduler's ``sched.*`` sections on the
+device trace's clock beside the ops they dispatched, each op under its
+model-step scopes (``docs/observability.md``).  Without ``--continuous`` the original
 lockstep batch runs: one shared cache length, prefill-everything-then-decode
 — kept as the baseline the scheduler has to beat.  Either way the decode hot
 path is the paper's §4 scenario: project to the vocabulary, fused
@@ -130,7 +133,6 @@ def _continuous(args, cfg, params) -> int:
                 r.prompt = r.prompt[:n - n % q]
     if args.metrics:
         obs_metrics.enable()
-        obs_kernels.enable_profiling()
     # --trace FILE shares one Tracer across replicas (pids split the
     # tracks); --trace DIR/ writes replica{i}.json per replica plus a
     # clock-aligned merged.json via repro.obs.merge
@@ -146,6 +148,10 @@ def _continuous(args, cfg, params) -> int:
                        for i in range(args.replicas)]
         else:
             tracer = obs_trace.Tracer(args.trace)
+    elif args.profile:
+        # the scheduler's sched.* profiler annotations come from its Tracer;
+        # with no --trace file it records in memory only
+        tracer = obs_trace.Tracer()
     router = ReplicaRouter(
         params, cfg, replicas=args.replicas,
         affinity=not args.no_affinity,
@@ -155,7 +161,11 @@ def _continuous(args, cfg, params) -> int:
         block_size=args.block_size,
         num_blocks=args.blocks or None,
         preempt=not args.no_preempt, tracer=tracer, tracers=tracers)
-    report = router.serve(requests)
+    if args.profile:
+        with jax.profiler.trace(args.profile, create_perfetto_trace=True):
+            report = router.serve(requests)
+    else:
+        report = router.serve(requests)
     if tracer is not None:
         tracer.close()
     merged_path = None
@@ -237,9 +247,6 @@ def _continuous(args, cfg, params) -> int:
         prof = obs_kernels.snapshot()
         for op, rec in prof["paths"].items():
             print(f"kernel path: {op} → {rec['path']} (×{rec['count']})")
-        for label, cost in prof["costs"].items():
-            print(f"kernel cost: {label} flops={cost['flops']:.4g} "
-                  f"bytes={cost['bytes_accessed']:.4g}")
         snap = obs_metrics.snapshot()
         for mname, rec in snap.items():
             if rec.get("type") != "histogram":
@@ -248,7 +255,11 @@ def _continuous(args, cfg, params) -> int:
                   f"mean={rec['mean']:.4g} p50={rec['p50']:.4g} "
                   f"p95={rec['p95']:.4g}")
         print(f"metrics: {len(snap)} instruments recorded")
-    if tracer is not None:
+    if args.profile:
+        print(f"profile: {args.profile} (sched.* host spans and the "
+              f"device's ops on one clock; open the run with xprof, or "
+              f"its perfetto_trace.json.gz in Perfetto)")
+    if args.trace and tracer is not None:
         print(f"trace: {len(tracer.events)} events → {args.trace} "
               f"(open in Perfetto, or: python -m repro.obs.report "
               f"{args.trace})")
@@ -323,9 +334,15 @@ def main(argv=None):
                          "one replica{i}.json per replica plus a "
                          "clock-aligned merged.json")
     ap.add_argument("--metrics", action="store_true",
-                    help="enable the repro.obs metrics registry + kernel "
-                         "cost profiling; prints dispatch paths and a "
-                         "snapshot summary after the run")
+                    help="enable the repro.obs metrics registry; prints "
+                         "dispatch paths and a snapshot summary after the "
+                         "run")
+    ap.add_argument("--profile", default="",
+                    help="record a JAX profiler trace of the serve loop "
+                         "into this directory (continuous mode): the "
+                         "scheduler's sched.* spans on the device trace's "
+                         "clock, and the device ops under their model-step "
+                         "scopes")
     ap.add_argument("--kv-cache-dtype", default="",
                     help="override the config's KV-cache dtype (e.g. int8: "
                          "quantized K/V with per-position scales, dequantized "

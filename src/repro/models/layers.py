@@ -170,16 +170,18 @@ def paged_cache_write(pool: Array, new: Array, cache_len,
     guarantees distinct rows never write the same (block, offset) — shared
     prefix blocks are copy-on-write'd by ``serving.paged`` before any write —
     except idle rows (length 0), which all land harmlessly in the sentinel
-    block the allocator never hands out."""
-    b, t = new.shape[:2]
-    bs = pool.shape[2]
-    ln = jnp.broadcast_to(jnp.asarray(cache_len, jnp.int32), (b,))
-    pos = ln[:, None] + jnp.arange(t, dtype=jnp.int32)          # [B, t]
-    bids = jnp.take_along_axis(jnp.asarray(block_tables, jnp.int32),
-                               pos // bs, axis=1)
-    offs = pos % bs
-    flat = new.astype(pool.dtype).reshape((b * t,) + new.shape[2:])
-    return pool.at[bids.reshape(-1), :, offs.reshape(-1)].set(flat)
+    block the allocator never hands out.  Scoped ``pool.write`` in the
+    program's op metadata."""
+    with jax.named_scope("pool.write"):
+        b, t = new.shape[:2]
+        bs = pool.shape[2]
+        ln = jnp.broadcast_to(jnp.asarray(cache_len, jnp.int32), (b,))
+        pos = ln[:, None] + jnp.arange(t, dtype=jnp.int32)      # [B, t]
+        bids = jnp.take_along_axis(jnp.asarray(block_tables, jnp.int32),
+                                   pos // bs, axis=1)
+        offs = pos % bs
+        flat = new.astype(pool.dtype).reshape((b * t,) + new.shape[2:])
+        return pool.at[bids.reshape(-1), :, offs.reshape(-1)].set(flat)
 
 
 def _valid_len(cache_len, t: int, b: int) -> Array:

@@ -135,26 +135,26 @@ def block_apply(kind: str, p: PyTree, x: Array, cfg: ModelConfig, *,
         raise ValueError(f"paged KV cache serves standard attention blocks "
                          f"only (got {kind!r})")
     if kind in ("dense", "moe", "mla", "shared_attn"):
-        h = _norm(cfg, p["ln1"], x)
         attn_cache = None if cache is None else cache["attn"]
-        if kind == "mla":
-            a, new_attn_cache = L.mla_apply(p["attn"], h, cfg,
-                                            positions=positions,
-                                            cache=attn_cache,
-                                            cache_len=cache_len)
-        else:
-            a, new_attn_cache = L.attention_apply(p["attn"], h, cfg,
-                                                  positions=positions,
-                                                  cache=attn_cache,
-                                                  cache_len=cache_len,
-                                                  block_tables=block_tables)
-        x = x + a
-        h = _norm(cfg, p["ln2"], x)
-        if kind == "moe":
-            m, aux = L.moe_apply(p["moe"], h, cfg)
-        else:
-            m = L.mlp_apply(p["mlp"], h, cfg)
-        x = x + m
+        with jax.named_scope("attn"):
+            h = _norm(cfg, p["ln1"], x)
+            if kind == "mla":
+                a, new_attn_cache = L.mla_apply(p["attn"], h, cfg,
+                                                positions=positions,
+                                                cache=attn_cache,
+                                                cache_len=cache_len)
+            else:
+                a, new_attn_cache = L.attention_apply(
+                    p["attn"], h, cfg, positions=positions, cache=attn_cache,
+                    cache_len=cache_len, block_tables=block_tables)
+            x = x + a
+        with jax.named_scope("mlp"):
+            h = _norm(cfg, p["ln2"], x)
+            if kind == "moe":
+                m, aux = L.moe_apply(p["moe"], h, cfg)
+            else:
+                m = L.mlp_apply(p["mlp"], h, cfg)
+            x = x + m
         new_cache = None if new_attn_cache is None else {"attn": new_attn_cache}
         return x, new_cache, aux
     if kind == "mamba":
@@ -241,35 +241,39 @@ def forward(params: PyTree, tokens: Array, cfg: ModelConfig, *,
     segs = block_pattern(cfg)
     new_caches: list = []
     aux_total: dict = {}
-    for si, (kind, count) in enumerate(segs):
-        if kind == "shared_attn":
-            cache = None if caches is None else caches[si]
-            step = _maybe_remat(
-                cfg, functools.partial(block_apply, "shared_attn", cfg=cfg,
-                                       positions=positions,
-                                       cache_len=cache_len,
-                                       block_tables=block_tables),
-                inference=caches is not None)
-            x, nc, _ = step(params["shared_attn"], x, cache=cache)
-            new_caches.append(nc)
-            continue
-        seg_params = params["segments"][si]
-        seg_cache = None if caches is None else caches[si]
+    # one scope over every segment: a scan's implicit per-layer slicing of
+    # its stacked params and caches (and the write-back) lands under it
+    with jax.named_scope("layers"):
+        for si, (kind, count) in enumerate(segs):
+            if kind == "shared_attn":
+                cache = None if caches is None else caches[si]
+                step = _maybe_remat(
+                    cfg, functools.partial(block_apply, "shared_attn",
+                                           cfg=cfg, positions=positions,
+                                           cache_len=cache_len,
+                                           block_tables=block_tables),
+                    inference=caches is not None)
+                x, nc, _ = step(params["shared_attn"], x, cache=cache)
+                new_caches.append(nc)
+                continue
+            seg_params = params["segments"][si]
+            seg_cache = None if caches is None else caches[si]
 
-        def body(x, layer_in, kind=kind):
-            p_i, cache_i = layer_in
-            out, nc, aux = block_apply(kind, p_i, x, cfg,
-                                       positions=positions, cache=cache_i,
-                                       cache_len=cache_len,
-                                       block_tables=block_tables)
-            return out, (nc, aux)
+            def body(x, layer_in, kind=kind):
+                p_i, cache_i = layer_in
+                out, nc, aux = block_apply(kind, p_i, x, cfg,
+                                           positions=positions,
+                                           cache=cache_i,
+                                           cache_len=cache_len,
+                                           block_tables=block_tables)
+                return out, (nc, aux)
 
-        body = _maybe_remat(cfg, body, inference=caches is not None)
-        x, (nc_stack, aux_stack) = jax.lax.scan(
-            body, x, (seg_params, seg_cache))
-        new_caches.append(nc_stack)
-        for k, v in (aux_stack or {}).items():
-            aux_total[k] = aux_total.get(k, 0.0) + jnp.sum(v)
+            body = _maybe_remat(cfg, body, inference=caches is not None)
+            x, (nc_stack, aux_stack) = jax.lax.scan(
+                body, x, (seg_params, seg_cache))
+            new_caches.append(nc_stack)
+            for k, v in (aux_stack or {}).items():
+                aux_total[k] = aux_total.get(k, 0.0) + jnp.sum(v)
     x = _norm(cfg, params["final_norm"], x)
     return x, new_caches, aux_total
 

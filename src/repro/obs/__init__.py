@@ -9,13 +9,14 @@ Four seams, all stdlib-only at import time:
   latencies deterministically.
 - :mod:`repro.obs.trace` — a :class:`~repro.obs.trace.Tracer` emitting
   Chrome ``trace_event`` spans (request lifecycle + per-tick scheduler
-  work) to a file that both Perfetto and ``repro.obs.report`` can read.
+  work) to a file that both Perfetto and ``repro.obs.report`` can read;
+  its nested spans are also ``sched.*`` annotations on the JAX profiler's
+  clock (``jax`` is imported on the first span, not at import).
 - :mod:`repro.obs.metrics` — process-wide registry of counters / gauges /
   histograms.  Off by default; every instrument method is a guarded no-op
   when the registry is disabled.
 - :mod:`repro.obs.kernels` — records which dispatch path each op resolved
-  to, the autotune decisions used, and XLA cost-analysis FLOPs/bytes for
-  compiled serving steps.
+  to and the autotune decisions used.
 - :mod:`repro.obs.history` / :mod:`repro.obs.regress` — the performance
   regression sentry: an append-only JSONL store of ``benchmarks/run.py
   --json`` records keyed by env fingerprint, and the noise-aware detector

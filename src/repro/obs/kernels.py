@@ -1,38 +1,16 @@
-"""Kernel profiling hooks: dispatch paths, autotune decisions, XLA costs.
+"""Kernel hooks: dispatch paths and autotune decisions.
 
 ``kernels/dispatch.py`` calls :func:`record_path` when an op resolves and
-:func:`record_autotune` when an autotune decision is used; the serving
-layer calls :func:`profile_jitted` (gated behind :func:`enable_profiling`)
-to attach ``compat.cost_analysis`` FLOPs/bytes to its compiled step.  All
-recording is plain-dict bookkeeping — no jax import at module load — so
-the hooks cost nothing measurable on the dispatch fast path.
+:func:`record_autotune` when an autotune decision is used.  All recording is
+plain-dict bookkeeping — no jax import at module load — so the hooks cost
+nothing measurable on the dispatch fast path.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Optional
-
-from repro.obs import metrics as _metrics
+from typing import Any, Dict
 
 _paths: Dict[str, Dict[str, Any]] = {}
 _autotune: Dict[str, Dict[str, Any]] = {}
-_costs: Dict[str, Dict[str, float]] = {}
-_profiling = False
-
-
-def enable_profiling() -> None:
-    """Arm :func:`profile_jitted` (cost analysis forces a compile, so it
-    is opt-in even when metrics are on)."""
-    global _profiling
-    _profiling = True
-
-
-def disable_profiling() -> None:
-    global _profiling
-    _profiling = False
-
-
-def profiling_enabled() -> bool:
-    return _profiling
 
 
 def record_path(op: str, path: str, *, prefer_pallas: bool = False) -> None:
@@ -49,45 +27,13 @@ def record_autotune(kind: str, key: Any, decision: Dict[str, Any]) -> None:
     _autotune[f"{kind}/{key}"] = dict(decision)
 
 
-def record_cost(label: str, analysis: Optional[Dict[str, Any]]) -> None:
-    """Store normalized FLOPs / bytes for a compiled computation."""
-    if not analysis:
-        return
-    flops = float(analysis.get("flops", 0.0) or 0.0)
-    nbytes = float(analysis.get("bytes accessed", 0.0) or 0.0)
-    _costs[label] = {"flops": flops, "bytes_accessed": nbytes}
-    if _metrics.enabled():
-        _metrics.gauge(f"kernels.{label}.flops").set(flops)
-        _metrics.gauge(f"kernels.{label}.bytes_accessed").set(nbytes)
-
-
-def profile_jitted(fn, label: str, *args, **kwargs) -> Optional[Dict[str, float]]:
-    """Cost-analyze a jitted callable on the given example args.
-
-    Lowering+compiling can be expensive and may hit paths XLA's analysis
-    does not support, so this never raises — failures record nothing.
-    Returns the stored cost dict, or None.
-    """
-    if not _profiling:
-        return None
-    try:
-        from repro import compat
-        compiled = fn.lower(*args, **kwargs).compile()
-        record_cost(label, compat.cost_analysis(compiled))
-    except Exception:
-        return None
-    return _costs.get(label)
-
-
 def snapshot() -> Dict[str, Any]:
     return {
         "paths": {k: dict(v) for k, v in sorted(_paths.items())},
         "autotune": {k: dict(v) for k, v in sorted(_autotune.items())},
-        "costs": {k: dict(v) for k, v in sorted(_costs.items())},
     }
 
 
 def reset() -> None:
     _paths.clear()
     _autotune.clear()
-    _costs.clear()

@@ -16,6 +16,14 @@ The output file is a valid JSON **array** written one event per line::
 
 so it both ``json.load``s (Perfetto-compatible) and can be parsed line by
 line by :mod:`repro.obs.report` without holding the whole file.
+
+A span entered as ``with tracer.span(name):`` is also a
+``jax.profiler.TraceAnnotation`` named ``sched.<name>`` for as long as it is
+open, so inside a ``jax.profiler`` trace the scheduler's sections sit on the
+device trace's clock next to the ops they dispatched.  Spans opened with
+:meth:`Tracer.begin` (request phases that outlive a tick) stay in the JSON
+record only: a profiler annotation must close on the thread, and in the
+order, it opened.
 """
 from __future__ import annotations
 
@@ -27,6 +35,20 @@ from repro.obs import clock as _clock
 # one prebuilt encoder: json.dumps with non-default separators constructs a
 # fresh JSONEncoder per call, which roughly doubles per-event cost
 _ENCODE = json.JSONEncoder(separators=(",", ":")).encode
+
+# the profiler-side name of a ``Tracer.span``: ANNOTATION_PREFIX + span name
+ANNOTATION_PREFIX = "sched."
+_annotation = None          # jax.profiler.TraceAnnotation, bound on first use
+
+
+def _annotation_cls():
+    """``jax.profiler.TraceAnnotation``, imported on the first span so that
+    importing ``repro.obs`` stays stdlib-only."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation
 
 
 class Span:
@@ -48,18 +70,22 @@ class Span:
 class _SpanCtx:
     """``with tracer.span(...)`` handle — a plain class, not a
     ``@contextmanager`` generator, because the generator protocol costs
-    ~1µs per use and spans are the hot path."""
+    ~1µs per use and spans are the hot path.  It holds the span's profiler
+    annotation open between ``__enter__`` and ``__exit__``."""
 
-    __slots__ = ("_tracer", "_span")
+    __slots__ = ("_tracer", "_span", "_annotation")
 
     def __init__(self, tracer: "Tracer", span: "Span"):
         self._tracer = tracer
         self._span = span
+        self._annotation = _annotation_cls()(ANNOTATION_PREFIX + span.name)
 
     def __enter__(self) -> "Span":
+        self._annotation.__enter__()
         return self._span
 
     def __exit__(self, *exc) -> None:
+        self._annotation.__exit__(None, None, None)
         self._tracer.end(self._span)
 
 
@@ -188,6 +214,8 @@ class Tracer:
     def span(self, name: str, *, tid: int = 0, pid: Optional[int] = None,
              cat: str = "serving", args: Optional[Dict[str, Any]] = None,
              ) -> _SpanCtx:
+        """A nested span for a ``with`` block on the calling thread; also a
+        profiler annotation ``sched.<name>`` while the block runs."""
         return _SpanCtx(self, self.begin(name, tid=tid, pid=pid, cat=cat,
                                          args=args))
 

@@ -69,12 +69,13 @@ def prefill(params: PyTree, tokens: Array, cfg: ModelConfig, *,
 def logits_from_hidden(params: PyTree, last_hidden: Array,
                        cfg: ModelConfig) -> Array:
     """LM-head logits [B, V] from the last-position hidden state [B, D],
-    with padded vocab rows masked to -inf."""
-    logits = transformer.logits_last(params, last_hidden[:, None], cfg)
-    if cfg.real_vocab_size and cfg.real_vocab_size < cfg.vocab_size:
-        mask = jnp.arange(cfg.vocab_size) < cfg.real_vocab_size
-        logits = jnp.where(mask, logits, float("-inf"))
-    return logits
+    with padded vocab rows masked to -inf (scoped ``head``)."""
+    with jax.named_scope("head"):
+        logits = transformer.logits_last(params, last_hidden[:, None], cfg)
+        if cfg.real_vocab_size and cfg.real_vocab_size < cfg.vocab_size:
+            mask = jnp.arange(cfg.vocab_size) < cfg.real_vocab_size
+            logits = jnp.where(mask, logits, float("-inf"))
+        return logits
 
 
 def decode_step(params: PyTree, caches: list, cache_len: Array,
@@ -205,14 +206,15 @@ def sample_per_slot(rngs: Array, logits: Array, top_k: int,
     which is what makes continuous batching reproduce single-sequence decode
     token-for-token.  The single vocab pass (paper Alg. 4) goes through the
     dispatch registry (Pallas kernel on TPU); only the Gumbel draw is
-    per-row."""
-    if temperature != 1.0:
-        logits = logits / temperature
+    per-row.  Scoped ``sample``."""
     from repro.kernels import dispatch
-    out = dispatch.softmax_topk(logits, top_k)
-    k = out.values.shape[-1]
-    g = jax.vmap(lambda r: jax.random.gumbel(r, (k,), jnp.float32))(rngs)
-    return core.gumbel_pick(out, g)
+    with jax.named_scope("sample"):
+        if temperature != 1.0:
+            logits = logits / temperature
+        out = dispatch.softmax_topk(logits, top_k)
+        k = out.values.shape[-1]
+        g = jax.vmap(lambda r: jax.random.gumbel(r, (k,), jnp.float32))(rngs)
+        return core.gumbel_pick(out, g)
 
 
 def decode_step_slots(params: PyTree, caches: list, slot_lens: Array,

@@ -92,7 +92,6 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.obs import clock as obs_clock
-from repro.obs import kernels as obs_kernels
 from repro.obs import metrics as obs_metrics
 from repro.serving import cache_family, engine
 
@@ -317,6 +316,11 @@ def drain_and_refill_occupancy(decode_lens, num_slots: int) -> float:
     return sum(decode_lens) / float(steps * num_slots)
 
 
+# what ``ContinuousScheduler._span`` hands back without a tracer (a
+# nullcontext holds no state, so one serves every call)
+_NO_SPAN = contextlib.nullcontext()
+
+
 # ---------------------------------------------------------------------------
 # Compiled step functions — shared across scheduler instances via lru_cache
 # (ModelConfig is frozen/hashable), so a fresh scheduler (or a benchmark's
@@ -503,8 +507,9 @@ class ContinuousScheduler:
     tracer / trace_pid:
         Optional ``repro.obs.trace.Tracer``: request-lifecycle spans go to
         track ``rid + 1``, scheduler ticks to track 0, under process id
-        ``trace_pid`` (the replica index).  ``None`` — the default — keeps
-        the hot path free of any tracing work.
+        ``trace_pid`` (the replica index).  The tick's nested sections are
+        also ``sched.*`` annotations on the JAX profiler's clock.  ``None``
+        — the default — keeps the hot path free of any tracing work.
     """
 
     def __init__(self, params, cfg: ModelConfig, *, num_slots: int,
@@ -530,7 +535,6 @@ class ContinuousScheduler:
         self._queued_spans: dict[int, object] = {}     # rid → open queued span
         self._metrics = (self._build_metrics(trace_pid)
                          if obs_metrics.enabled() else None)
-        self._profiled = False          # one cost-analysis per scheduler
         if paged:
             from repro.serving import paged as paged_mod
             self.pool = paged_mod.PagedPool(cfg, num_slots, slot_len,
@@ -622,9 +626,10 @@ class ContinuousScheduler:
         return rid + 1
 
     def _span(self, name: str, *, tid: int = 0, args=None):
-        """Scheduler-side span context; a no-op without a tracer."""
+        """Scheduler-side span context, also a ``sched.<name>`` profiler
+        annotation (``Tracer.span``); a no-op without a tracer."""
         if self.tracer is None:
-            return contextlib.nullcontext()
+            return _NO_SPAN
         return self.tracer.span(name, tid=tid, pid=self._pid, args=args)
 
     def _begin_phase(self, flight: _InFlight, name: str, args=None) -> None:
@@ -983,31 +988,28 @@ class ContinuousScheduler:
         while budget > 0 and pf["sizes"]:
             width = pf["sizes"].popleft()
             chunk = np.asarray(prompt[pf["pos"]:pf["pos"] + width])[None, :]
-            chunk_span = (self.tracer.begin(
-                "prefill_chunk", tid=self._tid(pf["flight"].req.rid),
-                pid=self._pid, args={"pos": pf["pos"], "width": width})
-                if self.tracer is not None else None)
-            if self.paged and self.family.kind == "token":
-                # chunks write straight into the shared pool through this
-                # sequence's block-table row — no batch-1 scratch cache, no
-                # insert copy at the end
-                pf["last"], self.pool.caches, pf["length"] = \
-                    self._prefill_paged(
-                        self.params, self.pool.caches,
-                        self.pool.device_row(pf["flight"].slot),
-                        pf["length"], jnp.asarray(chunk))
-            else:
-                # unpaged slots AND paged fixed-state: single-sequence
-                # prefill into the scratch cache (state installs into its
-                # pool block at finish)
-                pf["last"], pf["caches"], pf["length"] = self._prefill_step(
-                    self.params, pf["caches"], pf["length"],
-                    jnp.asarray(chunk))
-            pf["pos"] += width
-            self.prefill_chunks += 1
-            budget -= 1
-            if chunk_span is not None:
-                self.tracer.end(chunk_span)
+            with self._span("prefill_chunk",
+                            tid=self._tid(pf["flight"].req.rid),
+                            args={"pos": pf["pos"], "width": width}):
+                if self.paged and self.family.kind == "token":
+                    # chunks write straight into the shared pool through
+                    # this sequence's block-table row — no batch-1 scratch
+                    # cache, no insert copy at the end
+                    pf["last"], self.pool.caches, pf["length"] = \
+                        self._prefill_paged(
+                            self.params, self.pool.caches,
+                            self.pool.device_row(pf["flight"].slot),
+                            pf["length"], jnp.asarray(chunk))
+                else:
+                    # unpaged slots AND paged fixed-state: single-sequence
+                    # prefill into the scratch cache (state installs into
+                    # its pool block at finish)
+                    pf["last"], pf["caches"], pf["length"] = \
+                        self._prefill_step(self.params, pf["caches"],
+                                           pf["length"], jnp.asarray(chunk))
+                pf["pos"] += width
+                self.prefill_chunks += 1
+                budget -= 1
         if pf["sizes"]:
             return
         self._finish_prefill()
@@ -1020,27 +1022,23 @@ class ContinuousScheduler:
         flight = pf["flight"]
         seq = pf["seq"]
         bos = jnp.full((1, 1), engine.ENCDEC_BOS, jnp.int32)
-        span = (self.tracer.begin(
-            "prefill_chunk", tid=self._tid(flight.req.rid), pid=self._pid,
-            args={"pos": 0, "width": len(flight.req.prompt),
-                  "encoder_skipped": bool(seq.matched)})
-            if self.tracer is not None else None)
-        if seq.matched:
-            nc = self.pool.max_blocks - 1
-            cross = self._encdec_gather_cross(
-                self.pool.caches, jnp.asarray(seq.blocks[:nc], jnp.int32))
-            pf["last"], pf["caches"], pf["length"] = \
-                self._encdec_prefill_cached(self.params, cross, bos)
-        else:
-            frames = engine.encdec_frames_from_ids(flight.req.prompt,
-                                                   self.cfg)
-            pf["last"], pf["caches"], pf["length"] = self._encdec_prefill(
-                self.params, frames, bos)
-        pf["sizes"].clear()
-        pf["pos"] = len(flight.req.prompt)
-        self.prefill_chunks += 1
-        if span is not None:
-            self.tracer.end(span)
+        with self._span("prefill_chunk", tid=self._tid(flight.req.rid),
+                        args={"pos": 0, "width": len(flight.req.prompt),
+                              "encoder_skipped": bool(seq.matched)}):
+            if seq.matched:
+                nc = self.pool.max_blocks - 1
+                cross = self._encdec_gather_cross(
+                    self.pool.caches, jnp.asarray(seq.blocks[:nc], jnp.int32))
+                pf["last"], pf["caches"], pf["length"] = \
+                    self._encdec_prefill_cached(self.params, cross, bos)
+            else:
+                frames = engine.encdec_frames_from_ids(flight.req.prompt,
+                                                       self.cfg)
+                pf["last"], pf["caches"], pf["length"] = \
+                    self._encdec_prefill(self.params, frames, bos)
+            pf["sizes"].clear()
+            pf["pos"] = len(flight.req.prompt)
+            self.prefill_chunks += 1
         self._finish_prefill()
 
     def _finish_prefill(self) -> None:
@@ -1048,12 +1046,16 @@ class ContinuousScheduler:
         self._prefill = None
         flight: _InFlight = pf["flight"]
         rid = flight.req.rid
-        logits = self._logits(self.params, pf["last"])
-        tok = self._sample(self._key(rid, 0)[None], logits)
-        # the first sampled token closes the prefill phase: record it, then
-        # flip the lifecycle track to decode
-        self._record_token(flight, int(tok[0]))
-        self._begin_phase(flight, "decode")
+        with self._span("first_token"):
+            logits = self._logits(self.params, pf["last"])
+            tok = self._sample(self._key(rid, 0)[None], logits)
+            with self._span("first_token.wait"):
+                # the prompt's length is ready with its last hidden state
+                first, length = int(tok[0]), int(pf["length"])
+            # the first sampled token closes the prefill phase: record it,
+            # then flip the lifecycle track to decode
+            self._record_token(flight, first)
+            self._begin_phase(flight, "decode")
         if flight.remaining <= 0 or self._hit_eos(flight):
             self._finish(flight)
             return
@@ -1064,45 +1066,77 @@ class ContinuousScheduler:
             elif self.family.kind == "encdec":
                 self.pool.install_encdec(pf["seq"], pf["caches"])
             self.pool.finalize_prefill(pf["seq"])
-            self.pool.lens = self.pool.lens.at[slot].set(int(pf["length"]))
+            self.pool.lens = self.pool.lens.at[slot].set(length)
         else:
             slot = self.pool.acquire()
             assert slot is not None          # _admit gated on a free slot
-            self.pool.insert(slot, pf["caches"], int(pf["length"]))
+            self.pool.insert(slot, pf["caches"], length)
             flight.slot = slot
-        self.tokens = self.tokens.at[slot].set(int(tok[0]))
+        self.tokens = self.tokens.at[slot].set(first)
         self.active[slot] = flight
 
     # -- decode -------------------------------------------------------------
     def _decode_tick(self) -> None:
+        """One decode step over every active row, in four sections (each a
+        span under a tracer): ``decode.prepare`` backs each row's next write
+        position, ``decode.dispatch`` launches the jitted step,
+        ``decode.wait`` blocks on its tokens, ``decode.record`` books them.
+        Every point where the host blocks on the device is a ``*wait``
+        span."""
         if not self.active:
             return
         if self.paged:
-            # make every active row's next write position backed by an
-            # exclusively-owned block (allocate across boundaries, CoW shared
-            # blocks).  A row the pool cannot back — even after reclaiming
-            # cold prefix-cache blocks inside prepare_write — first swaps out
-            # strictly-lower-priority decodes (they resume bit-identically);
-            # only with no such victim left is it evicted, returning its
-            # non-shared blocks to the free list in this same tick
-            lens_pre = np.asarray(self.pool.lens)
-            for slot in list(self.active):
-                flight = self.active.get(slot)
-                if flight is None:          # swapped out as a victim above
-                    continue
-                ok = self.pool.prepare_write(slot, int(lens_pre[slot]))
-                while not ok:
-                    blocks_before = self.pool.free_blocks
-                    if not self._preempt_one(flight.req.priority):
-                        break
-                    ok = self.pool.prepare_write(slot, int(lens_pre[slot]))
-                    if not ok and self.pool.free_blocks <= blocks_before:
-                        break               # victim freed nothing usable
-                if not ok:
-                    flight.result.evicted = True
-                    self._finish(flight)
+            with self._span("decode.prepare"):
+                self._prepare_decode_writes()
             if not self.active:
                 return
+        with self._span("decode.dispatch"):
+            tok = self._dispatch_decode()
+        with self._span("decode.wait"):
+            tok_host = np.asarray(tok)
+            lens_host = np.asarray(self.pool.lens)  # one sync, not per slot
+        with self._span("decode.record"):
+            for slot in list(self.active):
+                flight = self.active[slot]
+                self._record_token(flight, int(tok_host[slot]))
+                slot_full = int(lens_host[slot]) >= self.pool.slot_len
+                if (flight.remaining <= 0 or self._hit_eos(flight)
+                        or slot_full):
+                    flight.result.evicted = (slot_full
+                                             and flight.remaining > 0
+                                             and not self._hit_eos(flight))
+                    self._finish(flight)
+
+    def _prepare_decode_writes(self) -> None:
+        """Make every active row's next write position backed by an
+        exclusively-owned block (allocate across boundaries, CoW shared
+        blocks).  A row the pool cannot back — even after reclaiming cold
+        prefix-cache blocks inside prepare_write — first swaps out
+        strictly-lower-priority decodes (they resume bit-identically); only
+        with no such victim left is it evicted, returning its non-shared
+        blocks to the free list in this same tick."""
+        with self._span("decode.lens_wait"):
+            lens_pre = np.asarray(self.pool.lens)
+        for slot in list(self.active):
+            flight = self.active.get(slot)
+            if flight is None:          # swapped out as a victim above
+                continue
+            ok = self.pool.prepare_write(slot, int(lens_pre[slot]))
+            while not ok:
+                blocks_before = self.pool.free_blocks
+                if not self._preempt_one(flight.req.priority):
+                    break
+                ok = self.pool.prepare_write(slot, int(lens_pre[slot]))
+                if not ok and self.pool.free_blocks <= blocks_before:
+                    break               # victim freed nothing usable
+            if not ok:
+                flight.result.evicted = True
+                self._finish(flight)
+
+    def _dispatch_decode(self):
+        """Launch the family's jitted decode step over the whole pool and
+        advance the device-side lengths; returns the sampled tokens without
+        waiting for them."""
         rids = np.full((self.pool.num_slots,), -1, np.int32)   # -1: idle slot
         produced = np.zeros((self.pool.num_slots,), np.int32)  # (sample dropped)
         active_mask = np.zeros((self.pool.num_slots,), bool)
@@ -1110,25 +1144,6 @@ class ContinuousScheduler:
             rids[s] = flight.req.rid
             produced[s] = flight.produced
             active_mask[s] = True
-        if not self._profiled and obs_kernels.profiling_enabled():
-            # one-time roofline hook: FLOPs / bytes of the compiled decode
-            # step via compat.cost_analysis (lower+compile hits the jit
-            # cache for shapes the step below compiles anyway)
-            self._profiled = True
-            if self.paged and self.family.kind != "token":
-                pass        # roofline hook covers the dense step shapes
-            elif self.paged:
-                obs_kernels.profile_jitted(
-                    self._decode_paged, "decode_step_paged", self.params,
-                    self.pool.caches,
-                    self.pool.device_tables(self.active.keys()),
-                    self.pool.lens, self.tokens[:, None], jnp.asarray(rids),
-                    jnp.asarray(produced), self.base_rng)
-            else:
-                obs_kernels.profile_jitted(
-                    self._decode, "decode_step", self.params,
-                    self.pool.caches, self.pool.lens, self.tokens[:, None],
-                    jnp.asarray(rids), jnp.asarray(produced), self.base_rng)
         if self.paged and self.family.kind == "state":
             # each active slot decodes in its own state row; inactive slots
             # gather the sentinel row and their writes drop
@@ -1176,19 +1191,10 @@ class ContinuousScheduler:
         if self.paged and self._suspended:
             # Overlap host→device swap-in staging with the decode step just
             # dispatched above: JAX queues the transfers asynchronously, so
-            # they run while we block on np.asarray(tok) below.  Bit-exact —
+            # they run while decode.wait blocks on the tokens.  Bit-exact —
             # swap_in consumes the staged device copies of the same payloads.
             self._prefetch_swap_in()
-        tok_host = np.asarray(tok)
-        lens_host = np.asarray(self.pool.lens)     # one sync, not per slot
-        for slot in list(self.active):
-            flight = self.active[slot]
-            self._record_token(flight, int(tok_host[slot]))
-            slot_full = int(lens_host[slot]) >= self.pool.slot_len
-            if flight.remaining <= 0 or self._hit_eos(flight) or slot_full:
-                flight.result.evicted = (slot_full and flight.remaining > 0
-                                         and not self._hit_eos(flight))
-                self._finish(flight)
+        return tok
 
     # -- bookkeeping --------------------------------------------------------
     def _record_token(self, flight: _InFlight, token: int) -> None:

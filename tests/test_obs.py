@@ -1,5 +1,5 @@
 """Observability layer (ISSUE 7): injectable clock, request-lifecycle
-tracing, metrics registry, kernel profiling hooks, and the trace report CLI.
+tracing, metrics registry, kernel path/autotune hooks, and the trace report CLI.
 
 The load-bearing claims:
 
@@ -392,29 +392,32 @@ def test_engine_stats_attach_metrics_snapshot(model, registry):
 # Kernel profiling hooks.
 # ---------------------------------------------------------------------------
 def test_kernel_profile_paths_and_costs(model):
+    """The kernels layer records the path each op resolved to and every
+    autotune decision used; the profile holds nothing else, and ``reset``
+    empties it."""
     from repro.kernels import dispatch
     params, cfg = model
     obs_kernels.reset()
-    obs_kernels.enable_profiling()
-    try:
-        # record_path fires at jit-trace time; the shared decode steps were
-        # compiled by earlier tests, so resolve an op explicitly too
-        path, _ = dispatch.lookup("softmax_topk")
-        eng = _engine(params, cfg)
-        eng.serve(_workload(n=2))
-        prof = eng.kernel_profile()
-    finally:
-        obs_kernels.disable_profiling()
+    # record_path fires at jit-trace time; the shared decode steps were
+    # compiled by earlier tests, so resolve an op explicitly too
+    path, _ = dispatch.lookup("softmax_topk")
+    decision = dispatch.block_decision(cfg.vocab_size)
+    eng = _engine(params, cfg)
+    eng.serve(_workload(n=2))
+    prof = eng.kernel_profile()
+    assert set(prof) == {"paths", "autotune"}
     assert prof["paths"], "dispatch must have recorded resolved paths"
     assert prof["paths"]["softmax_topk"]["path"] == path
     for entry in prof["paths"].values():
         assert entry["path"] in ("pallas", "interpret", "xla")
         assert entry["count"] >= 1
-    cost = prof["costs"]["decode_step"]
-    assert cost["flops"] > 0
-    assert cost["bytes_accessed"] > 0
+    blocks = {k: v for k, v in prof["autotune"].items()
+              if k.startswith("block/")}
+    assert decision.to_dict() in blocks.values(), prof["autotune"]
+    assert decision.block in {min(b, cfg.vocab_size)
+                              for b in dispatch.BLOCK_CANDIDATES}
     obs_kernels.reset()
-    assert eng.kernel_profile() == {"paths": {}, "autotune": {}, "costs": {}}
+    assert eng.kernel_profile() == {"paths": {}, "autotune": {}}
 
 
 # ---------------------------------------------------------------------------
