@@ -307,10 +307,11 @@ def paged_flash_decode(q: Array, k_pool: Array, v_pool: Array,
     """Paged decode attention: q [B,Hq,D]; pools [P,Hkv,BS,D]; block_tables
     [B,M]; kv_valid_len [B] → [B,Hq,D].
 
-    The KV tile width is the pool block size (no free tile knob — paging
-    fixes the gather granularity), so nothing resolves through
-    ``attention_tiles`` here.  ``k_scale_pool``/``v_scale_pool`` [P,Hkv,BS]
-    select the quantized (int8 pools + per-position scale pages) form."""
+    The kernel walks each row's live pages in compute blocks of
+    ``flash_decode.BLOCK_TOKENS`` tokens (no free tile knob), so nothing
+    resolves through ``attention_tiles`` here.
+    ``k_scale_pool``/``v_scale_pool`` [P,Hkv,BS] select the quantized (int8
+    pools + per-position scale pages) form."""
     return flash_decode_paged_pallas(q, k_pool, v_pool, block_tables,
                                      kv_valid_len,
                                      k_scale_pool=_scale_column(k_scale_pool),

@@ -6,8 +6,10 @@ the TPU compiler accepts its tiling: block shapes whose last two dims are not
 lower each kernel of the paged serving path at smollm_360m's published widths
 (15 query heads, 5 KV heads, head_dim 64, vocab 49152) for a ``v5e:2x2``
 topology described without the chip, and check that the compiled program
-holds the Mosaic kernel (``tpu_custom_call``).  Nothing runs; a pass says the
-compiler accepted the kernel, not that its numbers are right.
+holds the Mosaic kernel (``tpu_custom_call``); the paged decode kernel also
+at each benchmark cell's geometry, mistral-nemo-12b's widths included.
+Nothing runs; a pass says the compiler accepted the kernel, not that its
+numbers are right.
 
 Every autotune candidate the chip sweeps would compile is covered too: the
 vocab-axis blocks (``dispatch.BLOCK_CANDIDATES``) and the paged-prefill
@@ -15,6 +17,7 @@ vocab-axis blocks (``dispatch.BLOCK_CANDIDATES``) and the paged-prefill
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -80,6 +83,7 @@ def _assert_kernel(kernel, *args, **kwargs):
     fn = jax.jit(functools.partial(kernel, interpret=False, **tiles))
     text = fn.lower(*args, **operands).compile().as_text()
     assert "tpu_custom_call" in text
+    return text
 
 
 def _pools(dtype, quantized, sh, pages=P, hkv=HKV, bs=BS):
@@ -89,15 +93,32 @@ def _pools(dtype, quantized, sh, pages=P, hkv=HKV, bs=BS):
     return pools, scales
 
 
+# The benchmark cells' decode geometries: (Hq, Hkv, D, rows, table width,
+# pool blocks).  smollm-360m.chat: 64 slots x 2048 tokens; the
+# mistral-nemo-12b.l8 stage: 32 slots x 4096 tokens.
+DECODE_CELLS = {
+    "smollm-360m": (HQ, HKV, D, 64, 2048 // BS, 4096),
+    "mistral-nemo-12b.l8": (32, 8, 128, 32, 4096 // BS, 6144),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(DECODE_CELLS))
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
-def test_paged_decode_compiles(one_chip, no_compile_cache, quantized):
+def test_paged_decode_compiles(one_chip, no_compile_cache, quantized, cell):
+    """Both forms at each cell's widths and geometry.  The benchmark's
+    reader finds the kernel by the HLO name of its custom call."""
     sh = one_chip
-    (kp, vp), (ks, vs) = _pools(jnp.int8 if quantized else jnp.bfloat16,
-                                quantized, sh)
-    args = [_spec((SLOTS, HQ, D), jnp.bfloat16, sh), kp, vp,
-            _spec((SLOTS, M), jnp.int32, sh), _spec((SLOTS,), jnp.int32, sh)]
-    _assert_kernel(flash_decode_paged_pallas, *args, k_scale_pool=ks,
-                   v_scale_pool=vs)
+    hq, hkv, dh, rows, m, blocks = DECODE_CELLS[cell]
+    dtype = jnp.int8 if quantized else jnp.bfloat16
+    pool = _spec((blocks + 1, hkv, BS, dh), dtype, sh)
+    scale = _spec((blocks + 1, hkv, BS, 1), jnp.bfloat16, sh)
+    args = [_spec((rows, hq, dh), jnp.bfloat16, sh), pool, pool,
+            _spec((rows, m), jnp.int32, sh), _spec((rows,), jnp.int32, sh)]
+    text = _assert_kernel(flash_decode_paged_pallas, *args,
+                          k_scale_pool=scale if quantized else None,
+                          v_scale_pool=scale if quantized else None)
+    assert re.search(r"%flash_decode_paged_pallas(\.\d+)? = \S+ custom-call\("
+                     r"[^\n]*custom_call_target=\"tpu_custom_call\"", text)
 
 
 @pytest.mark.parametrize("tq", [CHUNK, 64, 1])

@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 from repro import core
-from repro.kernels import dispatch, ops
+from repro.kernels import dispatch, flash_decode, ops
 
 B, HQ, HKV, D = 3, 4, 2, 16
 BS, M = 8, 4                       # block size, max blocks per row
@@ -35,7 +35,7 @@ def pool():
 
 def _gathered(pool_arr, tables):
     g = jnp.swapaxes(pool_arr[tables], 2, 3)
-    return g.reshape(tables.shape[0], -1, HKV, D)
+    return g.reshape(tables.shape[0], -1, HKV, pool_arr.shape[-1])
 
 
 # ---------------------------------------------------------------------------
@@ -91,6 +91,79 @@ def test_paged_decode_shared_blocks(pool):
     vlen = jnp.asarray([19, 19, 19], jnp.int32)
     out = ops.paged_flash_decode(q, k_pool, v_pool, jnp.asarray(shared), vlen)
     np.testing.assert_array_equal(np.asarray(out[0]), np.asarray(out[1]))
+
+
+# ---------------------------------------------------------------------------
+# Decode's row loop: tables several compute blocks wide, lane folds.
+# ---------------------------------------------------------------------------
+LOOP_BS = 16                                   # 8 pages a compute block
+LOOP_PAGES = flash_decode.BLOCK_TOKENS // LOOP_BS
+LOOP_M = 2 * LOOP_PAGES + 4                    # 2.5 compute blocks wide
+LOOP_VLENS = [
+    (LOOP_PAGES * LOOP_BS - 1, LOOP_PAGES * LOOP_BS,
+     LOOP_PAGES * LOOP_BS + 1),                # a compute-block edge, ±1
+    (0, 2 * LOOP_PAGES * LOOP_BS + 1, 0),      # idle rows beside a long one
+    (LOOP_M * LOOP_BS, 0, 1),                  # full table, idle, one token
+]
+
+
+def _loop_pools(dh, quantized, seed):
+    """Scattered live pages; the sentinel page 0 holds NaN (float pools) or a
+    huge scale (int8), so a dead table entry that reached the output
+    would show."""
+    rng = np.random.default_rng(seed)
+    p = B * LOOP_M + 1
+    shape = (p, HKV, LOOP_BS, dh)
+    ks = vs = None
+    if quantized:
+        k, v = (jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+                for _ in range(2))
+        ks, vs = (jnp.asarray(rng.uniform(0.01, 0.2, shape[:3]),
+                              jnp.bfloat16).at[0].set(1e4)
+                  for _ in range(2))
+    else:
+        k, v = (jnp.asarray(rng.normal(size=shape), jnp.float32)
+                .at[0].set(jnp.nan) for _ in range(2))
+    tables = rng.permutation(p - 1)[:B * LOOP_M].reshape(B, LOOP_M) + 1
+    return k, v, ks, vs, tables
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["float", "int8"])
+@pytest.mark.parametrize("dh,fold", [(16, 8), (64, 2), (128, 1)])
+@pytest.mark.parametrize("vlens", LOOP_VLENS)
+def test_paged_decode_row_loop(vlens, dh, fold, quantized):
+    """Live pages are walked a compute block at a time, across block edges;
+    entries past a row's live pages point at the sentinel, which must not
+    reach the output; an idle row (``vlen == 0``) writes zeros.  ``dh <
+    128`` runs the lane fold, whose slices score tokens out of order."""
+    assert flash_decode._lane_fold(LOOP_BS, dh) == fold
+    k, v, ks, vs, tables = _loop_pools(dh, quantized, seed=dh)
+    vlen = np.asarray(vlens, np.int32)
+    live = -(-vlen // LOOP_BS)
+    for b, n in enumerate(live):
+        tables[b, n:] = 0
+    tables = jnp.asarray(tables, jnp.int32)
+    rng = np.random.default_rng(12)
+    q = jnp.asarray(rng.normal(size=(B, HQ, dh)).astype(np.float32))
+    got = np.asarray(ops.paged_flash_decode(q, k, v, tables,
+                                            jnp.asarray(vlen),
+                                            k_scale_pool=ks,
+                                            v_scale_pool=vs))
+    assert np.isfinite(got).all()
+    if quantized:
+        kf = _dequant_gathered(k, ks, tables)
+        vf = _dequant_gathered(v, vs, tables)
+    else:
+        kf, vf = _gathered(k, tables), _gathered(v, tables)
+    for b in range(B):
+        if vlen[b] == 0:
+            np.testing.assert_array_equal(got[b], 0.0)
+            continue
+        n = int(vlen[b])
+        want = core.naive_attention(q[b:b + 1, None], kf[b:b + 1, :n],
+                                    vf[b:b + 1, :n], causal=False)[0, 0]
+        np.testing.assert_allclose(got[b], np.asarray(want), rtol=2e-5,
+                                   atol=2e-5)
 
 
 # ---------------------------------------------------------------------------
