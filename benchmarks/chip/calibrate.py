@@ -8,7 +8,7 @@ For each seed it does what ``run_cell.py`` does (weights from the seed, a
 fresh engine, the cell's traffic for its pre-window and ``--seconds``),
 then compares the same sampled requests twice: the served tokens against
 the float32 reference (the program's reading), and the token the control
-(the reference with float8 matrix products, ``reference.py``) puts first
+(the cell's reference module with float8 matrix products) puts first
 (the control's reading).  Each reading goes through the decision that
 ``run_cell.py`` makes, at the cell's committed limit: the program's has to
 come out correct and the control's not.  The limit goes between the

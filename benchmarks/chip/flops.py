@@ -3,9 +3,12 @@ knows (model sizes, live contexts, chunk offsets and widths).  Never from
 the implementation's cost analysis, so a number means the same whatever
 kernel implements the op.
 
-``m`` is a model-size dict as ``harness.Cell.model`` gives it.
-Attention counts the keys each query needs (causal), and bytes count each
-operand read once and each result written once.
+``m`` is a model-size dict as ``harness.Cell.model`` gives it; its
+``"reference"`` names the architecture module (``reference.arch``) whose
+counts these build on: its matmul FLOPs, its attention FLOPs at each
+query's keys and its K/V bytes written and read.  A dict that names none
+(sizes written out by hand) is counted as ``dense_gqa``; every cell's names
+its own.  Bytes count each operand read once and each result written once.
 """
 from __future__ import annotations
 
@@ -13,6 +16,8 @@ import json
 import os
 
 import numpy as np
+
+import reference
 
 BYTES = {"bfloat16": 2, "float16": 2, "float32": 4, "int8": 1}
 
@@ -27,18 +32,20 @@ def peaks(device_kind: str) -> dict:
     return table["devices"][device_kind]
 
 
-def layer_matmul_flops(m: dict) -> int:
-    """Projection and MLP FLOPs of one token through one layer."""
-    d, hq, hkv, hd, f = (m["d_model"], m["num_heads"], m["num_kv_heads"],
-                         m["head_dim"], m["d_ff"])
-    return 2 * (d * hq * hd + 2 * d * hkv * hd + hq * hd * d + 3 * d * f)
+def _arch(m: dict):
+    return reference.arch(m.get("reference", "dense_gqa"))
+
+
+def layer_matmul_flops(m: dict) -> float:
+    """Projection and MLP FLOPs of one token through one layer, the mean
+    over the layers."""
+    return _arch(m).matmul_flops(m) / m["num_layers"]
 
 
 def attention_flops(m: dict, keys) -> float:
     """QK^T and PV FLOPs of queries that each attend ``keys`` positions,
     summed over the queries and the layers."""
-    return (4.0 * m["num_heads"] * m["head_dim"] * m["num_layers"]
-            * float(np.sum(keys)))
+    return _arch(m).attention_flops(m, keys)
 
 
 def head_flops(m: dict) -> int:
@@ -49,7 +56,7 @@ def decode_flops(m: dict, contexts) -> float:
     """Model FLOPs of decode rows whose new token sees ``contexts`` keys
     (the token itself included): layers, attention and the LM head."""
     n = len(contexts)
-    return (n * (m["num_layers"] * layer_matmul_flops(m) + head_flops(m))
+    return (n * (_arch(m).matmul_flops(m) + head_flops(m))
             + attention_flops(m, contexts))
 
 
@@ -58,22 +65,21 @@ def prefill_flops(m: dict, offset: int, width: int) -> float:
     ``offset`` cached ones (no LM head: the first token's is counted with
     the request by ``head_flops``)."""
     keys = offset + 1 + np.arange(width)
-    return (width * m["num_layers"] * layer_matmul_flops(m)
+    return (width * _arch(m).matmul_flops(m)
             + attention_flops(m, keys))
 
 
 def kv_bytes_per_token(m: dict) -> int:
-    """K and V of one position over all layers, in the served dtype."""
-    return (2 * m["num_layers"] * m["num_kv_heads"] * m["head_dim"]
-            * BYTES[m["dtype"]])
+    """K and V written for one position over all layers, in the served
+    dtype."""
+    return _arch(m).kv_bytes_per_token(m)
 
 
 def paged_decode_cost(m: dict, contexts) -> tuple:
     """(FLOPs, bytes) of the paged decode attention over rows of
     ``contexts`` keys, every layer: K/V read once, q read and out written."""
-    contexts = np.asarray(contexts, np.float64)
     q_out = 2 * m["num_heads"] * m["head_dim"] * BYTES[m["dtype"]]
-    nbytes = (kv_bytes_per_token(m) * contexts.sum()
+    nbytes = (_arch(m).kv_bytes_read(m, contexts)
               + m["num_layers"] * q_out * len(contexts))
     return attention_flops(m, contexts), float(nbytes)
 
@@ -83,7 +89,7 @@ def paged_prefill_cost(m: dict, offset: int, width: int) -> tuple:
     layer: causal keys per query; K/V of the whole context read once."""
     keys = offset + 1 + np.arange(width)
     q_out = 2 * width * m["num_heads"] * m["head_dim"] * BYTES[m["dtype"]]
-    nbytes = (kv_bytes_per_token(m) * (offset + width)
+    nbytes = (_arch(m).kv_bytes_read(m, [offset + width])
               + m["num_layers"] * q_out)
     return attention_flops(m, keys), float(nbytes)
 

@@ -2,10 +2,15 @@
 and the check of what the window served.
 
 Everything a cell is made of is found by name from ``BENCHMARK.json``: its
-configuration file (``configs/<config>.json``), its traffic file
-(``traffic/<traffic>.json``) and one reader per per-layer metric
-(``layer_metrics/<metric>.py``).  A later cell, mix or metric is a new file
-and a new entry; this module does not change for it.
+configuration file (``configs/<config>.json``), the architecture module
+that file names (``references/<reference>.py``: the reference forward, the
+sizes the program is checked against and the counts ``flops.py`` builds
+on), its traffic file (``traffic/<traffic>.json``) and one reader per
+per-layer metric (``layer_metrics/<metric>.py``).  A later cell, mix or
+metric is a new file and a new entry; so is a configuration of another
+architecture: a reference module, a configuration file, a traffic file,
+readers and ``BENCHMARK.json`` entries, beside the program's own change.
+This module does not change for any of them.
 
 The program under test is the serving stack in ``src/repro``: one paged
 ``Engine`` driven tick by tick.  The benchmark takes from it only what it
@@ -32,17 +37,6 @@ ROOT = os.path.dirname(os.path.dirname(BENCH_DIR))
 import loadgen  # noqa: E402
 import reference  # noqa: E402
 
-# configuration-file key -> the program's ModelConfig field
-CONFIG_KEYS = {
-    "hidden_size": "d_model", "intermediate_size": "d_ff",
-    "num_hidden_layers": "num_layers", "num_attention_heads": "num_heads",
-    "num_key_value_heads": "num_kv_heads", "head_dim": "head_dim",
-    "vocab_size": "vocab_size", "rope_theta": "rope_theta",
-    "rms_norm_eps": "norm_eps", "tie_word_embeddings": "tie_embeddings",
-    "torch_dtype": "dtype",
-}
-
-
 WARM_RID = 2**30          # the warm-up request's id, above any traffic id
 
 
@@ -63,20 +57,16 @@ class Cell:
     per_layer: list         # BENCHMARK.json per_layer entries of this cell
 
     @property
+    def arch(self):
+        """The architecture module the configuration file names."""
+        return reference.arch(self.config["reference"])
+
+    @property
     def model(self) -> dict:
-        """Sizes in the reference's names."""
-        c = self.config
-        return {"vocab_size": c["vocab_size"], "d_model": c["hidden_size"],
-                "num_layers": c["num_hidden_layers"],
-                "num_heads": c["num_attention_heads"],
-                "num_kv_heads": c["num_key_value_heads"],
-                "head_dim": c["head_dim"], "d_ff": c["intermediate_size"],
-                "rope_theta": float(c["rope_theta"]),
-                "norm_eps": float(c["rms_norm_eps"]),
-                "tie_embeddings": bool(c["tie_word_embeddings"]),
-                "dtype": c["torch_dtype"],
-                "embedding_scale": float(
-                    c.get("weights", {}).get("embedding_scale", 1.0))}
+        """Sizes in the architecture module's names, and the module's
+        name (``flops.py`` counts by it)."""
+        return dict(self.arch.model(self.config),
+                    reference=self.config["reference"])
 
     @property
     def serving(self) -> dict:
@@ -101,11 +91,15 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
         raise SystemExit(f"no workload {name!r} in BENCHMARK.json")
     w = wl[name]
     cfg_entry = {c["name"]: c for c in bench["configs"]}[w["config"]]
+    config = _json(os.path.join(root, cfg_entry["file"]))
+    if "reference" not in config:
+        raise ValueError(f"{cfg_entry['file']} names no reference module "
+                         "(its \"reference\" key)")
+    reference.arch(config["reference"])
     e2e = [m for m in bench["end_to_end"] if _covers(m, name, set())]
     reported = {m["name"] for m in e2e}
     layer = [m for m in bench["per_layer"] if _covers(m, name, reported)]
-    return Cell(name=name, chips=int(w["chips"]),
-                config=_json(os.path.join(root, cfg_entry["file"])),
+    return Cell(name=name, chips=int(w["chips"]), config=config,
                 traffic=_json(os.path.join(BENCH_DIR, "traffic",
                                            w["traffic"] + ".json")),
                 end_to_end=e2e, per_layer=layer)
@@ -163,18 +157,40 @@ class CompileClock:
             self.count += 1
 
 
+def _program_get(cfg, field: str):
+    """A ``ModelConfig`` field, dotted where nested; the head size and the
+    vocabulary as the model uses them (unpadded)."""
+    if field == "head_dim":
+        return cfg.resolved_head_dim
+    if field == "vocab_size":
+        return cfg.real_vocab_size or cfg.vocab_size
+    for part in field.split("."):
+        cfg = getattr(cfg, part)
+    return cfg
+
+
+def _program_set(cfg, field: str, value):
+    head, _, rest = field.partition(".")
+    if rest:
+        value = _program_set(getattr(cfg, head), rest, value)
+    return dataclasses.replace(cfg, **{head: value})
+
+
 def program_config(cell: Cell):
-    """The program's ModelConfig for the cell; every size in the file must
-    be what runs."""
+    """The program's ModelConfig for the cell: each key the file lists
+    under ``reduced`` set from the file, then every size of the
+    architecture module's ``program_fields`` checked against what runs."""
     from repro import configs
     c = cell.config
-    cfg = configs.get(c["arch"]).replace(num_layers=c["num_hidden_layers"])
-    for key, field in CONFIG_KEYS.items():
-        got = getattr(cfg, field)
-        if field == "head_dim":
-            got = cfg.resolved_head_dim
-        if field == "vocab_size" and cfg.real_vocab_size:
-            got = cfg.real_vocab_size
+    fields = dict(cell.arch.program_fields)
+    cfg = configs.get(c["arch"])
+    for key in c.get("reduced", ()):
+        if key not in fields:
+            raise ValueError(f"{cell.name}: reduced key {key!r} has no "
+                             "program field in the reference module")
+        cfg = _program_set(cfg, fields[key], c[key])
+    for key, field in fields.items():
+        got = _program_get(cfg, field)
         if got != c[key]:
             raise ValueError(f"{cell.name}: the program's {field} is {got}, "
                              f"the configuration file says {key}={c[key]}")
@@ -189,7 +205,7 @@ def make_params(cell: Cell, cfg, seed: int):
     import jax
     import jax.numpy as jnp
     from repro.models.params import init_params
-    scale = cell.model["embedding_scale"]
+    scale = float(cell.config.get("weights", {}).get("embedding_scale", 1.0))
 
     def build(key):
         params, _ = init_params(cfg, key)
@@ -543,17 +559,19 @@ def pick_sample(finished: list, seed: int, want_tokens: int,
 
 def compare(cell: Cell, seed: int, sample: list, prompts: dict,
             control: Optional[str] = None) -> dict:
-    """The reference's gaps over the sampled requests (see
-    ``reference.served_gaps``); with ``control``, the control's too."""
+    """The reference's gaps over the sampled requests, by the cell's
+    architecture module (see ``reference.served_gaps``); with ``control``,
+    the control's too."""
     key = reference.key_for_seed(seed)
+    mod, model = cell.arch, cell.model
     gaps, ctl = [], []
     for r in sample:
         prompt = prompts[r.rid]
         length = cell.serving["slot_len"]
-        gaps.append(reference.served_gaps(key, cell.model, prompt, r.tokens,
+        gaps.append(reference.served_gaps(mod, key, model, prompt, r.tokens,
                                           length))
         if control:
-            ctl.append(reference.served_gaps(key, cell.model, prompt,
+            ctl.append(reference.served_gaps(mod, key, model, prompt,
                                              r.tokens, length,
                                              control=control))
     out = {"max_logit_gap": float(max(g.max() for g in gaps)) if gaps

@@ -26,13 +26,14 @@ from repro import configs  # noqa: E402
 from repro.serving import scheduler  # noqa: E402
 
 SEED = 2**40 + 17
+dense_gqa = reference.arch("dense_gqa")
 
 
 def _cell(arch: str, loop: str, dtype: str = "float32", limit=1e-3,
           **sizes):
     cfg = configs.get_smoke(arch).replace(dtype=dtype, **sizes)
     config = {
-        "arch": arch, "hidden_size": cfg.d_model,
+        "arch": arch, "reference": "dense_gqa", "hidden_size": cfg.d_model,
         "intermediate_size": cfg.d_ff, "num_hidden_layers": cfg.num_layers,
         "num_attention_heads": cfg.num_heads,
         "num_key_value_heads": cfg.num_kv_heads,
@@ -90,7 +91,7 @@ def test_reference_draws_the_program_weights(arch, scale):
     dt = jnp.bfloat16
     emb = params["embedding"]
     normal = jax.jit(reference._normal, static_argnums=(1, 2, 3))
-    want = jax.jit(lambda k: reference._embedding(k, m, dt))(k_e)
+    want = jax.jit(lambda k: dense_gqa._embedding(k, m, dt))(k_e)
     assert np.array_equal(want, np.asarray(emb["embed"], np.float32))
     if scale != 1.0:
         assert not np.array_equal(want, normal(
@@ -102,7 +103,7 @@ def test_reference_draws_the_program_weights(arch, scale):
         assert np.array_equal(head, np.asarray(emb["head"], np.float32))
     seg = params["segments"][0]
     for layer, lkey in enumerate(jax.random.split(k_s, m["num_layers"])):
-        w = jax.jit(lambda k: reference._layer_weights(k, m, dt))(lkey)
+        w = jax.jit(lambda k: dense_gqa._layer_weights(k, m, dt))(lkey)
         for name in ("wq", "wk", "wv", "wo"):
             assert np.array_equal(
                 w[name], np.asarray(seg["attn"][name][layer], np.float32))
